@@ -48,6 +48,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 import jax  # noqa: E402
 
+from repro import obs  # noqa: E402
 from repro.bsp import (PartitionRuntime, RunOptions, bfs,  # noqa: E402
                        build_app, make_fused_runner, pagerank, ref, sssp)
 from repro.core import scaled_paper_cluster  # noqa: E402
@@ -82,16 +83,10 @@ BACKENDS = ("scatter", "segment")
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-class CompileClock:
-    """Seconds JAX spent in backend compiles since the clock started."""
-
-    def __init__(self):
-        self.total = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, seconds, **_):
-        if event == COMPILE_EVENT:
-            self.total += seconds
+def compile_seconds() -> float:
+    """Seconds JAX spent in backend compiles so far, as ``repro.obs``
+    counts them."""
+    return obs.totals()["counts"].get(COMPILE_EVENT, 0.0)
 
 
 def emit(rec: dict) -> None:
@@ -175,17 +170,17 @@ def steps_of(app: str) -> int:
     return PR_ITERS if app == "pagerank" else MAX_STEPS
 
 
-def phase_graph500(seed: int, clock: CompileClock) -> dict:
+def phase_graph500(seed: int) -> dict:
     g, rt, root, info = partitioned_graph500(GRAPH500_SCALE, (2, 6), seed)
     pr_ref, dist_ref, ref_s = references(g, root)
     runs = []
     for backend in BACKENDS:
         opts = RunOptions(backend=backend, fused=True)
         for app, entry in APP_ENTRY.items():
-            c0, t = clock.total, time.perf_counter()
+            c0, t = compile_seconds(), time.perf_counter()
             out, actives = entry(rt, root, opts)
             call_s = time.perf_counter() - t
-            compile_s = clock.total - c0
+            compile_s = compile_seconds() - c0
             runs.append({"backend": backend, "app": app,
                          "supersteps": len(actives), "call_s": call_s,
                          "compile_s": compile_s,
@@ -244,7 +239,7 @@ def khop_oracle(svc: SamplingService, seeds: np.ndarray, key) -> list:
     return hops
 
 
-def phase_sampling(seed: int, clock: CompileClock) -> dict:
+def phase_sampling(seed: int) -> dict:
     t = time.perf_counter()
     g = road_mesh(ROAD_SIDE, seed=seed)
     cl = scaled_paper_cluster(2, 6, g.num_edges)
@@ -253,10 +248,10 @@ def phase_sampling(seed: int, clock: CompileClock) -> dict:
     setup_s = time.perf_counter() - t
     k_seeds, k_batch = jax.random.split(jax.random.PRNGKey(seed))
     seeds = svc.local_seeds(0, BATCH, k_seeds)
-    c0, t = clock.total, time.perf_counter()
+    c0, t = compile_seconds(), time.perf_counter()
     batch = svc.sample(seeds, k_batch, home=0)
     first_s = time.perf_counter() - t
-    compile_s = clock.total - c0
+    compile_s = compile_seconds() - c0
     t = time.perf_counter()
     again = svc.sample(seeds, k_batch, home=0)
     run_s = time.perf_counter() - t
@@ -336,15 +331,15 @@ def main(argv=None) -> int:
               f"{len(devices)} device(s)", file=sys.stderr)
         return 2
     cache = enable_compile_cache()
-    clock = CompileClock()
+    obs.listen()
     emit({"phase": "setup", "compile_cache": cache,
           "jax": jax.__version__, "devices": len(devices)})
     if args.chips == 4:
         emit(phase_mesh(args.seed, args.chips))
     else:
-        emit(phase_graph500(args.seed, clock))
+        emit(phase_graph500(args.seed))
         emit(phase_pallas(args.seed))
-        emit(phase_sampling(args.seed, clock))
+        emit(phase_sampling(args.seed))
     d = jax.devices()[0]
     emit({"ok": True, "device": {"platform": d.platform,
                                  "kind": d.device_kind,

@@ -38,7 +38,9 @@ make supersteps O(frontier) instead of O(E_local).  BFS's frontier
 Every app wrapper runs on either engine runner: the per-step oracle
 (``run_bsp``, default) or the fused on-device loop (``fused=True`` /
 ``tol=`` → ``run_bsp_fused``), and backend opts such as
-``message_dtype="bfloat16"`` flow through ``**backend_opts``.
+``message_dtype="bfloat16"`` flow through ``**backend_opts``.  Each call
+is one ``bsp.call`` request of ``repro.obs``: ``bsp.prepare``, the
+supersteps, ``bsp.gather``.
 """
 from __future__ import annotations
 
@@ -49,8 +51,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .backends import BACKENDS, MESSAGE_DTYPES, get_backend
-from .engine import run_bsp, run_bsp_fused
+from .engine import run_bsp, run_bsp_fused, to_device, to_host
 from .partition_runtime import PartitionRuntime
 
 #: apps whose state is monotone under the semiring: they already early-exit
@@ -152,13 +155,13 @@ def _options(options: RunOptions | None, app: str, backend, fused, tol,
 
 def _static_tree(rt: PartitionRuntime):
     return {
-        "edges": jnp.asarray(rt.local_edges),
-        "edge_valid": jnp.asarray(rt.edge_valid),
-        "edge_weight": jnp.asarray(rt.edge_weight),
-        "vertex_valid": jnp.asarray(rt.vertex_valid),
-        "global_degree": jnp.asarray(rt.global_degree),
-        "weighted_degree": jnp.asarray(rt.weighted_degree),
-        "rep_slot": jnp.asarray(rt.rep_slot),
+        "edges": to_device(rt.local_edges),
+        "edge_valid": to_device(rt.edge_valid),
+        "edge_weight": to_device(rt.edge_weight),
+        "vertex_valid": to_device(rt.vertex_valid),
+        "global_degree": to_device(rt.global_degree),
+        "weighted_degree": to_device(rt.weighted_degree),
+        "rep_slot": to_device(rt.rep_slot),
     }
 
 
@@ -206,6 +209,24 @@ def _run(spec: "AppSpec", num_steps: int, *, mesh=None, fused=False,
                    mesh=mesh, check_rep=spec.check_rep)
 
 
+def _call(app: str, rt, opts: RunOptions, num_steps: int, mesh,
+          build: Callable[..., "AppSpec"], *args, **kw):
+    """One app call as a ``bsp.call`` request: prepare (``build(*args,
+    **kw)`` with the options' backend: the backend's layout, the static
+    tree and the initial state, uploaded), run, gather.  Returns
+    ``(global result, actives)``."""
+    runner = "fused" if opts.fused or opts.tol is not None else "stepwise"
+    with obs.request("bsp.call", app=app, runner=runner,
+                     backend=opts.backend, supersteps=num_steps):
+        with obs.span("bsp.prepare"):
+            spec = build(*args, backend=opts.backend,
+                         **opts.backend_opts(), **kw)
+        out, actives = _run(spec, num_steps, mesh=mesh, fused=opts.fused,
+                            tol=opts.tol, chunk=opts.chunk)
+        with obs.span("bsp.gather"):
+            return spec.finalize(rt, out), actives
+
+
 # ---------------------------------------------------------------------------
 # PageRank (dense: every vertex/edge active every superstep; (+, ×))
 # ---------------------------------------------------------------------------
@@ -239,18 +260,17 @@ def build_pagerank(rt: PartitionRuntime, damping: float = 0.85, *,
         return {"pr": new_pr}, active
 
     if init is None:
-        pr0 = jnp.where(jnp.asarray(rt.vertex_valid),
+        pr0 = jnp.where(to_device(rt.vertex_valid),
                         1.0 / n, 0.0).astype(jnp.float32)
     else:
-        pr0 = jnp.asarray(
+        pr0 = to_device(
             rt.scatter_global(np.asarray(init, dtype=np.float32),
-                              fill=1.0 / n),
-            dtype=jnp.float32)
-        pr0 = jnp.where(jnp.asarray(rt.vertex_valid), pr0, 0.0)
+                              fill=1.0 / n))
+        pr0 = jnp.where(to_device(rt.vertex_valid), pr0, 0.0)
     state = {"pr": pr0}
     # isolated vertices (no incident edge, hence in no partition) hold the
     # teleport mass only:
-    fin = lambda rt, out: rt.gather_global(np.asarray(out["pr"]),
+    fin = lambda rt, out: rt.gather_global(to_host(out["pr"]),
                                            fill=(1.0 - damping) / n)
     return AppSpec("pagerank", superstep, state, static, eb.check_rep, fin)
 
@@ -269,11 +289,8 @@ def pagerank(rt: PartitionRuntime, num_iters: int = 20,
     validated object."""
     opts, extra = _options(options, "pagerank", backend, fused, tol,
                            chunk, backend_opts)
-    spec = build_pagerank(rt, damping, backend=opts.backend, init=init,
-                          **opts.backend_opts(), **extra)
-    out, actives = _run(spec, num_iters, mesh=mesh, fused=opts.fused,
-                        tol=opts.tol, chunk=opts.chunk)
-    return spec.finalize(rt, out), actives
+    return _call("pagerank", rt, opts, num_iters, mesh, build_pagerank,
+                 rt, damping, init=init, **extra)
 
 
 # ---------------------------------------------------------------------------
@@ -304,9 +321,9 @@ def build_relax(rt: PartitionRuntime, source: int, weighted: bool, *,
     dist0 = np.full((rt.p, rt.vmax), np.inf, dtype=np.float32)
     holders = np.nonzero(rt.local_vertex_gid == source)
     dist0[holders] = 0.0
-    state = {"dist": jnp.asarray(dist0),
-             "changed": jnp.asarray(np.isfinite(dist0))}
-    fin = lambda rt, out: rt.gather_global(np.asarray(out["dist"]),
+    state = {"dist": to_device(dist0),
+             "changed": to_device(np.isfinite(dist0))}
+    fin = lambda rt, out: rt.gather_global(to_host(out["dist"]),
                                            fill=np.inf)
     return AppSpec(name, superstep, state, static, eb.check_rep, fin)
 
@@ -316,11 +333,8 @@ def sssp(rt: PartitionRuntime, source: int = 0, num_iters: int = 30,
          tol=None, chunk=8, **backend_opts):
     opts, extra = _options(options, "sssp", backend, fused, tol, chunk,
                            backend_opts)
-    spec = build_relax(rt, source, weighted=True, backend=opts.backend,
-                       **opts.backend_opts(), **extra)
-    out, actives = _run(spec, num_iters, mesh=mesh, fused=opts.fused,
-                        tol=opts.tol, chunk=opts.chunk)
-    return spec.finalize(rt, out), actives
+    return _call("sssp", rt, opts, num_iters, mesh, build_relax,
+                 rt, source, weighted=True, **extra)
 
 
 # ---------------------------------------------------------------------------
@@ -349,9 +363,9 @@ def build_bfs(rt: PartitionRuntime, source: int, *, backend="scatter",
     dist0 = np.full((rt.p, rt.vmax), np.inf, dtype=np.float32)
     holders = np.nonzero(rt.local_vertex_gid == source)
     dist0[holders] = 0.0
-    state = {"dist": jnp.asarray(dist0),
+    state = {"dist": to_device(dist0),
              "step": jnp.zeros(rt.p, dtype=jnp.float32)}
-    fin = lambda rt, out: rt.gather_global(np.asarray(out["dist"]),
+    fin = lambda rt, out: rt.gather_global(to_host(out["dist"]),
                                            fill=np.inf)
     return AppSpec("bfs", superstep, state, static, eb.check_rep, fin)
 
@@ -361,11 +375,8 @@ def bfs(rt: PartitionRuntime, source: int = 0, num_iters: int = 30,
         tol=None, chunk=8, **backend_opts):
     opts, extra = _options(options, "bfs", backend, fused, tol, chunk,
                            backend_opts)
-    spec = build_bfs(rt, source, backend=opts.backend,
-                     **opts.backend_opts(), **extra)
-    out, actives = _run(spec, num_iters, mesh=mesh, fused=opts.fused,
-                        tol=opts.tol, chunk=opts.chunk)
-    return spec.finalize(rt, out), actives
+    return _call("bfs", rt, opts, num_iters, mesh, build_bfs,
+                 rt, source, **extra)
 
 
 # ---------------------------------------------------------------------------
@@ -387,12 +398,12 @@ def build_components(rt: PartitionRuntime, *, backend="scatter",
         new_changed = new < lab
         return {"lab": new, "changed": new_changed}, new_changed.sum()
 
-    lab0 = jnp.where(jnp.asarray(rt.vertex_valid),
-                     jnp.asarray(rt.local_vertex_gid, dtype=jnp.float32),
+    lab0 = jnp.where(to_device(rt.vertex_valid),
+                     to_device(rt.local_vertex_gid.astype(np.float32)),
                      jnp.inf)
     # every valid vertex broadcasts its own label once, on superstep 1
-    state = {"lab": lab0, "changed": jnp.asarray(rt.vertex_valid)}
-    fin = lambda rt, out: rt.gather_global(np.asarray(out["lab"]),
+    state = {"lab": lab0, "changed": to_device(rt.vertex_valid)}
+    fin = lambda rt, out: rt.gather_global(to_host(out["lab"]),
                                            fill=np.inf)
     return AppSpec("cc", superstep, state, static, eb.check_rep, fin)
 
@@ -403,11 +414,8 @@ def connected_components(rt: PartitionRuntime, num_iters: int = 30,
     """Min-label propagation; returns (V,) component id per vertex."""
     opts, extra = _options(options, "cc", backend, fused, tol, chunk,
                            backend_opts)
-    spec = build_components(rt, backend=opts.backend,
-                            **opts.backend_opts(), **extra)
-    out, actives = _run(spec, num_iters, mesh=mesh, fused=opts.fused,
-                        tol=opts.tol, chunk=opts.chunk)
-    return spec.finalize(rt, out), actives
+    return _call("cc", rt, opts, num_iters, mesh, build_components,
+                 rt, **extra)
 
 
 #: app name -> AppSpec builder (benchmarks/dryrun iterate this)

@@ -84,7 +84,7 @@ import numpy as np
 
 from ..kernels.bsr_spmv import get_semiring
 from ..kernels.bsr_spmv.kernel import spmv_pallas
-from .engine import exchange
+from .engine import exchange, to_device
 
 #: weight kinds an app may ask for: the stored ⊗ operand per edge
 WEIGHT_KINDS = ("weight", "unit", "zero")
@@ -143,7 +143,9 @@ class EdgeBackend:
         extras, combine = self.prepare(rt, semiring, weights)
 
         def combine_exchanged(sa, x):
-            return exchange(combine(sa, x), sa["rep_slot"], r_pad, mode)
+            with jax.named_scope("bsp.edge_kernel"):
+                y = combine(sa, x)
+            return exchange(y, sa["rep_slot"], r_pad, mode)
 
         return extras, combine_exchanged
 
@@ -224,8 +226,8 @@ def _scatter_prepare_factory(message_dtype: str = "float32",
             slot = np.arange(len(s)) - np.searchsorted(s, s)
             ell_dst[i][s, slot] = dst2[i][valid2[i]][order]
             ell_w[i][s, slot] = w2[i][valid2[i]][order]
-        extras = {"eb_fr_dst": jnp.asarray(ell_dst),
-                  "eb_fr_w": jnp.asarray(ell_w)}
+        extras = {"eb_fr_dst": to_device(ell_dst),
+                  "eb_fr_w": to_device(ell_w)}
         none = _no_message(sr)
 
         def combine(sa, x):
@@ -282,9 +284,9 @@ def _segment_prepare(rt, semiring: str, weights: str,
     inc_out = np.take_along_axis(inc_out, order, 1)
     inc_in = np.take_along_axis(inc_in, order, 1).astype(np.int32)
     w2 = np.take_along_axis(w2, order, 1)
-    extras = {"eb_seg_out": jnp.asarray(inc_out),
-              "eb_seg_in": jnp.asarray(inc_in),
-              "eb_seg_w": jnp.asarray(w2)}
+    extras = {"eb_seg_out": to_device(inc_out),
+              "eb_seg_in": to_device(inc_in),
+              "eb_seg_w": to_device(w2)}
 
     def combine(sa, x):
         # low-precision messages, full-precision ⊕: ⊗ in message_dtype,
@@ -317,10 +319,10 @@ def _pallas_prepare_factory(block_size: int = 128,
         mdt = _message_dtype(message_dtype)
         bsr = rt.local_bsr(block_size=block_size, semiring=sr.name,
                            weights=weights, dtype=str(message_dtype))
-        extras = {"eb_bsr_cols": jnp.asarray(bsr.cols),
-                  "eb_bsr_blocks": jnp.asarray(bsr.blocks),
-                  "eb_bsr_gather": jnp.asarray(bsr.gather),
-                  "eb_bsr_rank": jnp.asarray(bsr.rank)}
+        extras = {"eb_bsr_cols": to_device(bsr.cols),
+                  "eb_bsr_blocks": to_device(bsr.blocks),
+                  "eb_bsr_gather": to_device(bsr.gather),
+                  "eb_bsr_rank": to_device(bsr.rank)}
 
         def combine(sa, x):
             # blocks are stored in message_dtype (LocalBSR dtype cache

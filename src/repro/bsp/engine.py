@@ -27,6 +27,11 @@ Two runners iterate that contract:
   on device.  One dispatch and one host sync for the entire run — on
   dispatch-bound shards (small per-machine edge sets) this is where the
   superstep wall clock actually goes.
+
+On the host every stepwise superstep is a ``bsp.superstep`` span
+(``repro.obs``); every device→host read goes through :func:`to_host` and
+every upload of the app path through :func:`to_device`, so the recorder
+counts them.
 """
 from __future__ import annotations
 
@@ -37,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
+
+from .. import obs
 
 
 MACHINES = "machines"
@@ -64,24 +71,45 @@ def exchange(partial: jnp.ndarray, rep_slot: jnp.ndarray, r_pad: int,
     Returns (Vmax,) with replicated entries replaced by the cross-machine
     combination (sum / min / max); non-replicated entries pass through.
     """
-    slot = jnp.where(rep_slot >= 0, rep_slot, r_pad)
-    if mode == "sum":
-        buf = jnp.zeros(r_pad + 1, dtype=partial.dtype)
-        buf = buf.at[slot].add(jnp.where(rep_slot >= 0, partial, 0))
-        tot = jax.lax.psum(buf, MACHINES)
-    elif mode == "min":
-        hi = _extreme(partial.dtype, +1)
-        buf = jnp.full(r_pad + 1, hi, dtype=partial.dtype)
-        buf = buf.at[slot].min(jnp.where(rep_slot >= 0, partial, hi))
-        tot = jax.lax.pmin(buf, MACHINES)
-    elif mode == "max":
-        lo = _extreme(partial.dtype, -1)
-        buf = jnp.full(r_pad + 1, lo, dtype=partial.dtype)
-        buf = buf.at[slot].max(jnp.where(rep_slot >= 0, partial, lo))
-        tot = jax.lax.pmax(buf, MACHINES)
-    else:
-        raise ValueError(mode)
-    return jnp.where(rep_slot >= 0, tot[slot], partial)
+    with jax.named_scope("bsp.exchange"):
+        slot = jnp.where(rep_slot >= 0, rep_slot, r_pad)
+        if mode == "sum":
+            buf = jnp.zeros(r_pad + 1, dtype=partial.dtype)
+            buf = buf.at[slot].add(jnp.where(rep_slot >= 0, partial, 0))
+            tot = jax.lax.psum(buf, MACHINES)
+        elif mode == "min":
+            hi = _extreme(partial.dtype, +1)
+            buf = jnp.full(r_pad + 1, hi, dtype=partial.dtype)
+            buf = buf.at[slot].min(jnp.where(rep_slot >= 0, partial, hi))
+            tot = jax.lax.pmin(buf, MACHINES)
+        elif mode == "max":
+            lo = _extreme(partial.dtype, -1)
+            buf = jnp.full(r_pad + 1, lo, dtype=partial.dtype)
+            buf = buf.at[slot].max(jnp.where(rep_slot >= 0, partial, lo))
+            tot = jax.lax.pmax(buf, MACHINES)
+        else:
+            raise ValueError(mode)
+        return jnp.where(rep_slot >= 0, tot[slot], partial)
+
+
+def to_device(x) -> jax.Array:
+    """Host→device upload of one array, counted in ``bsp.h2d_bytes``."""
+    x = np.asarray(x)
+    obs.count("bsp.h2d_bytes", x.nbytes)
+    return jnp.asarray(x)
+
+
+def to_host(x) -> np.ndarray:
+    """Device→host read: waits for the device, so it is a ``bsp.sync``
+    span and one ``bsp.host_syncs``."""
+    with obs.span("bsp.sync"):
+        obs.count("bsp.host_syncs")
+        return np.asarray(x)
+
+
+def _count_trace() -> None:
+    # Python in a jitted function runs only while JAX traces it
+    obs.count("bsp.retraces")
 
 
 def _bind_static(jitted, static, make_args=lambda *a: a):
@@ -110,12 +138,17 @@ def make_step(superstep: Callable, static, *, mesh: Mesh | None = None,
     (``pallas_call``; the edge-kernel backends declare this).
     """
     if mesh is None:
-        return _bind_static(
-            jax.jit(jax.vmap(superstep, axis_name=MACHINES)), static)
+        def vstep(state, static):
+            _count_trace()
+            return jax.vmap(superstep, axis_name=MACHINES)(state, static)
+
+        return _bind_static(jax.jit(vstep), static)
 
     state_spec_of = lambda tree: jax.tree.map(lambda _: P(MACHINES), tree)
 
     def step(state, static):
+        _count_trace()
+
         def inner(st, sa):
             st = jax.tree.map(lambda a: a[0], st)
             sa = jax.tree.map(lambda a: a[0], sa)
@@ -145,9 +178,10 @@ def run_bsp(superstep: Callable, state, static, num_steps: int,
     """
     step = make_step(superstep, static, mesh=mesh, check_rep=check_rep)
     actives = []
-    for _ in range(num_steps):
-        state, act = step(state)
-        actives.append(np.asarray(act))
+    for i in range(num_steps):
+        with obs.span("bsp.superstep", step_num=i):
+            state, act = step(state)
+            actives.append(to_host(act))
     if not actives:
         # zero steps still contract to a (0, p) actives array
         return state, np.zeros((0, _num_machines(state)))
@@ -232,6 +266,7 @@ def make_fused_runner(superstep: Callable, static, *,
 
         @jax.jit
         def fused(state, limits, static):
+            _count_trace()
             p = _num_machines(state)
             act_dt = jax.eval_shape(body, state, static)[1].dtype
             run = functools.partial(
@@ -248,8 +283,8 @@ def make_fused_runner(superstep: Callable, static, *,
             if num_steps <= 0:
                 return state, np.zeros((0, p))
             state, bufs, ts = dispatch(state, num_steps)
-            steps = int(np.asarray(ts).sum())
-            actives = np.asarray(bufs).reshape(-1, p)[:steps]
+            steps = int(to_host(ts).sum())
+            actives = to_host(bufs).reshape(-1, p)[:steps]
             return state, actives
 
         run.lower = dispatch.lower
@@ -287,6 +322,7 @@ def make_fused_runner(superstep: Callable, static, *,
 
     @jax.jit
     def fused(state, limits, static):
+        _count_trace()
         return jax.shard_map(
             sharded, mesh=mesh,
             in_specs=(state_spec_of(state), P(), state_spec_of(static)),
@@ -300,9 +336,9 @@ def make_fused_runner(superstep: Callable, static, *,
         if num_steps <= 0:
             return state, np.zeros((0, p))
         state, bufs, ts = dispatch(state, num_steps)
-        steps = int(np.asarray(ts)[0].sum())
+        steps = int(to_host(ts)[0].sum())
         # bufs: (p, num_chunks, chunk) -> (num_chunks*chunk, p), trimmed
-        actives = np.asarray(bufs).transpose(1, 2, 0).reshape(-1, p)[:steps]
+        actives = to_host(bufs).transpose(1, 2, 0).reshape(-1, p)[:steps]
         return state, actives
 
     run.lower = dispatch.lower

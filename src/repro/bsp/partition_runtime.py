@@ -27,6 +27,7 @@ import functools
 
 import numpy as np
 
+from .. import obs
 from ..core.graph import Graph
 
 
@@ -249,7 +250,9 @@ class PartitionRuntime:
                 raise ValueError(
                     f"create(source=<stream assignment>) takes only "
                     f"edge_weights=; got {bad + sorted(knobs)}")
-            return cls._pack_from_stream(source, edge_weights=edge_weights)
+            with obs.span("runtime.pack"):
+                return cls._pack_from_stream(source,
+                                             edge_weights=edge_weights)
         if not (hasattr(source, "edges") and hasattr(source, "num_vertices")):
             raise ValueError(
                 f"create: source must be a Graph or a StreamAssignment "
@@ -267,8 +270,9 @@ class PartitionRuntime:
                     "targets)")
             from ..core.partitioners import get
             assign = get(method)(source, cluster, **knobs)
-            return cls._pack_from_assignment(source, assign, cluster.p,
-                                             edge_weights=edge_weights)
+            with obs.span("runtime.pack"):
+                return cls._pack_from_assignment(source, assign, cluster.p,
+                                                 edge_weights=edge_weights)
         if assign is None:
             raise ValueError(
                 "create(source=graph) needs either assign= (+ p= or "
@@ -284,8 +288,9 @@ class PartitionRuntime:
                     "create(source=graph, assign=...) requires p= or "
                     "cluster= for the machine count")
             p = cluster.p
-        return cls._pack_from_assignment(source, assign, p,
-                                         edge_weights=edge_weights)
+        with obs.span("runtime.pack"):
+            return cls._pack_from_assignment(source, assign, p,
+                                             edge_weights=edge_weights)
 
     @classmethod
     def build(cls, g: Graph, assign: np.ndarray, p: int,
