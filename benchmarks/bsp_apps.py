@@ -6,7 +6,7 @@ per dataset) and swaps the *compute* layer — the edge-kernel backend each
 superstep combines messages through (``repro.bsp.backends``):
 
 * ``scatter`` — the gather-scatter oracle (`at[].⊕` per direction);
-* ``segment`` — sorted-CSR reduction (``jax.ops.segment_*``);
+* ``segment`` — segmented scan over the destination-sorted incidence;
 * ``pallas``  — the blocked Block-ELL semiring SpMV (interpret-mode on
   CPU, compiled on TPU; its ELL fill stats are the utilization proxy).
 

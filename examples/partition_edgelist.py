@@ -71,12 +71,12 @@ Choosing an edge-kernel backend
 the machine's local edges (``repro.bsp.backends``); results agree to
 1e-5, only speed and hardware shape differ:
 
-* ``scatter`` (default) — the gather-scatter oracle (``at[].add`` per
-  direction).  Slowest, but the reference every other backend is tested
-  against; pick it when validating a new partition pipeline.
-* ``segment`` — sorted-CSR reduction via a running sum differenced at
-  row pointers.  No scatter at all, ~5x the scatter superstep
-  throughput on CPU proxies; the right default for CPU runs.
+* ``segment`` (default) — each machine's incidence sorted by output
+  vertex once, reduced by a segmented scan that restarts at every
+  vertex's run.  No scatter at all.
+* ``scatter`` — the gather-scatter oracle (``at[].add`` per
+  direction), the reference every other backend is tested against;
+  pick it when validating a new partition pipeline.
 * ``pallas`` — the blocked Block-ELL semiring SpMV
   (``repro.kernels.bsr_spmv``) over the degree-sorted per-machine
   adjacency (``PartitionRuntime.local_bsr``).  MXU-shaped 128x128
@@ -329,7 +329,7 @@ def main(argv=None):
                     help="after partitioning, pack the BSP runtime from "
                          "the shards and run distributed PageRank")
     ap.add_argument("--pagerank-iters", type=int, default=30)
-    ap.add_argument("--backend", default="scatter",
+    ap.add_argument("--backend", default="segment",
                     choices=EDGE_BACKENDS,
                     help="edge-kernel backend for --pagerank (see "
                          "module docstring)")

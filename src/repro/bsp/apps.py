@@ -9,8 +9,10 @@ local adjacency, expressed through a pluggable **edge-kernel backend**
 (``bsp/backends.py``): PageRank combines under (+, ×) with edge weights,
 SSSP under (min, +), BFS expands its frontier under (or, and), and
 connected components propagates labels under (min, +) with zero weights.
-``backend="scatter"`` (default) is the historical gather-scatter loop and
-the float-exact oracle; ``"segment"`` is the sorted-CSR reduction;
+``"segment"`` (the default) reduces a destination-sorted incidence by a
+segmented scan, with no scatter; ``"scatter"`` is the historical
+gather-scatter loop, the oracle the others are tested against, and the
+frontier path (a ``frontier_cap`` with no backend named runs on it);
 ``"pallas"`` runs the blocked Block-ELL kernel (``kernels/bsr_spmv``) over
 ``rt.local_bsr()``.  Results agree across backends — bitwise for the
 min/max semirings, to ~1e-7 for (+, ×) — and under both vmap and a real
@@ -52,7 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from .backends import BACKENDS, MESSAGE_DTYPES, get_backend
+from .backends import BACKENDS, MESSAGE_DTYPES, default_backend, get_backend
 from .engine import run_bsp, run_bsp_fused, to_device, to_host
 from .partition_runtime import PartitionRuntime
 
@@ -73,7 +75,8 @@ class RunOptions:
     as a legacy spelling that assembles one internally — mixing both
     raises.
 
-    * ``backend`` — edge-kernel backend (``bsp/backends.py``).
+    * ``backend`` — edge-kernel backend (``bsp/backends.py``); None
+      picks ``segment``, or ``scatter`` when ``frontier_cap`` is given.
     * ``fused`` — run the whole iteration as one on-device dispatch.
     * ``tol`` — PageRank residual early-exit (implies ``fused``); the
       monotone apps (:data:`MONOTONE_APPS`) reject it.
@@ -82,12 +85,17 @@ class RunOptions:
     * ``frontier_cap`` — scatter-only frontier compaction width.
     """
 
-    backend: str = "scatter"
+    backend: str | None = None
     fused: bool = False
     tol: float | None = None
     chunk: int = 8
     message_dtype: str = "float32"
     frontier_cap: int | None = None
+
+    def __post_init__(self):
+        if self.backend is None:
+            object.__setattr__(self, "backend",
+                               default_backend(self.frontier_cap))
 
     def validate(self, app: str | None = None) -> "RunOptions":
         """Raise ``ValueError`` on bad knobs / combinations; returns self."""
@@ -134,7 +142,7 @@ def _options(options: RunOptions | None, app: str, backend, fused, tol,
     extra = dict(backend_opts)
     if options is not None:
         mixed = [name for name, val, default in
-                 (("backend", backend, "scatter"), ("fused", fused, False),
+                 (("backend", backend, None), ("fused", fused, False),
                   ("tol", tol, None), ("chunk", chunk, 8))
                  if val != default]
         mixed += sorted(k for k in ("message_dtype", "frontier_cap")
@@ -154,14 +162,13 @@ def _options(options: RunOptions | None, app: str, backend, fused, tol,
 
 
 def _static_tree(rt: PartitionRuntime):
+    """The per-vertex arrays the superstep bodies and the exchange read;
+    each backend uploads the edge arrays it reads in its ``extras``."""
     return {
-        "edges": to_device(rt.local_edges),
-        "edge_valid": to_device(rt.edge_valid),
-        "edge_weight": to_device(rt.edge_weight),
         "vertex_valid": to_device(rt.vertex_valid),
-        "global_degree": to_device(rt.global_degree),
         "weighted_degree": to_device(rt.weighted_degree),
         "rep_slot": to_device(rt.rep_slot),
+        "slot_vertex": to_device(rt.slot_vertex),
     }
 
 
@@ -232,7 +239,7 @@ def _call(app: str, rt, opts: RunOptions, num_steps: int, mesh,
 # ---------------------------------------------------------------------------
 
 def build_pagerank(rt: PartitionRuntime, damping: float = 0.85, *,
-                   backend="scatter", init: np.ndarray | None = None,
+                   backend=None, init: np.ndarray | None = None,
                    **backend_opts) -> AppSpec:
     """``init`` warm-starts from a previous run's (V,) global PageRank —
     the dynamic-epoch hand-off after ``PartitionRuntime.apply_delta``.
@@ -277,7 +284,7 @@ def build_pagerank(rt: PartitionRuntime, damping: float = 0.85, *,
 
 def pagerank(rt: PartitionRuntime, num_iters: int = 20,
              damping: float = 0.85, *, mesh=None, options=None,
-             backend="scatter", init: np.ndarray | None = None,
+             backend=None, init: np.ndarray | None = None,
              fused=False, tol=None, chunk=8, **backend_opts):
     """Returns (V,) global PageRank after ``num_iters`` supersteps.
 
@@ -298,7 +305,7 @@ def pagerank(rt: PartitionRuntime, num_iters: int = 20,
 # ---------------------------------------------------------------------------
 
 def build_relax(rt: PartitionRuntime, source: int, weighted: bool, *,
-                backend="scatter", name: str = "sssp",
+                backend=None, name: str = "sssp",
                 **backend_opts) -> AppSpec:
     inf = jnp.float32(jnp.inf)
     eb, static, combine = _resolve(rt, backend, "min_plus",
@@ -329,7 +336,7 @@ def build_relax(rt: PartitionRuntime, source: int, weighted: bool, *,
 
 
 def sssp(rt: PartitionRuntime, source: int = 0, num_iters: int = 30,
-         *, mesh=None, options=None, backend="scatter", fused=False,
+         *, mesh=None, options=None, backend=None, fused=False,
          tol=None, chunk=8, **backend_opts):
     opts, extra = _options(options, "sssp", backend, fused, tol, chunk,
                            backend_opts)
@@ -341,7 +348,7 @@ def sssp(rt: PartitionRuntime, source: int = 0, num_iters: int = 30,
 # BFS (sparse: frontier grows/shrinks; (or, and))
 # ---------------------------------------------------------------------------
 
-def build_bfs(rt: PartitionRuntime, source: int, *, backend="scatter",
+def build_bfs(rt: PartitionRuntime, source: int, *, backend=None,
               **backend_opts) -> AppSpec:
     """Layer-synchronous BFS: the frontier (vertices discovered last
     superstep) expands through one (or, and) product per step.  Distances
@@ -371,7 +378,7 @@ def build_bfs(rt: PartitionRuntime, source: int, *, backend="scatter",
 
 
 def bfs(rt: PartitionRuntime, source: int = 0, num_iters: int = 30,
-        *, mesh=None, options=None, backend="scatter", fused=False,
+        *, mesh=None, options=None, backend=None, fused=False,
         tol=None, chunk=8, **backend_opts):
     opts, extra = _options(options, "bfs", backend, fused, tol, chunk,
                            backend_opts)
@@ -383,7 +390,7 @@ def bfs(rt: PartitionRuntime, source: int = 0, num_iters: int = 30,
 # Weakly-connected components (label propagation: (min, +), zero weights)
 # ---------------------------------------------------------------------------
 
-def build_components(rt: PartitionRuntime, *, backend="scatter",
+def build_components(rt: PartitionRuntime, *, backend=None,
                      **backend_opts) -> AppSpec:
     inf = jnp.float32(jnp.inf)
     eb, static, combine = _resolve(rt, backend, "min_plus", "zero", "min",
@@ -409,7 +416,7 @@ def build_components(rt: PartitionRuntime, *, backend="scatter",
 
 
 def connected_components(rt: PartitionRuntime, num_iters: int = 30,
-                         *, mesh=None, options=None, backend="scatter",
+                         *, mesh=None, options=None, backend=None,
                          fused=False, tol=None, chunk=8, **backend_opts):
     """Min-label propagation; returns (V,) component id per vertex."""
     opts, extra = _options(options, "cc", backend, fused, tol, chunk,
@@ -428,7 +435,7 @@ APP_BUILDERS = {
 }
 
 
-def build_app(rt: PartitionRuntime, app: str, *, backend="scatter",
+def build_app(rt: PartitionRuntime, app: str, *, backend=None,
               **kw) -> AppSpec:
     """Build any registered app's :class:`AppSpec` by name."""
     try:

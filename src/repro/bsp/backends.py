@@ -12,20 +12,28 @@ A):
 A backend supplies that product.  ``prepare(rt, semiring, weights)``
 returns ``(extras, combine)``: ``extras`` is a dict of ``(p, ...)`` arrays
 merged into the superstep's static tree (vmap/shard_map stack them like
-every other runtime array), and ``combine(sa, x)`` maps this machine's
-``(Vmax,)`` vertex values to their ⊕-combined neighborhood values inside
-the (rank-reduced) superstep body.
+every other runtime array): the edge arrays it reads, and only those, so
+a call uploads no edge array its backend does not read.
+``combine(sa, x)`` maps this machine's ``(Vmax,)`` vertex values to
+their ⊕-combined neighborhood values inside the (rank-reduced) superstep
+body.
 
 Backends:
 
+``segment`` (the default, :func:`default_backend`)
+    Segmented scan, no scatter.  The doubled incidence is sorted by
+    output vertex once per runtime (:class:`SegmentLayout`, cached by
+    ``rt.layout``), so each vertex's messages form one contiguous run.
+    The combine gathers ``x`` once per message, ⊕-scans the messages in
+    log-depth shifted passes that restart at every run
+    (:func:`segmented_scan`), and reads each run's last row.  XLA lowers
+    a sorted ``segment_sum`` to a scatter on the TPU; this lowers to
+    dense vector work and two gathers.
 ``scatter``
     The historical gather-scatter loop (``at[dst].⊕(x[src] ⊗ w)``, one
     scatter per direction).  Kept as the oracle every other backend is
-    tested against — float-identical to the pre-backend apps.
-``segment``
-    Sorted-CSR reduction.  The incidence list is pre-sorted by output
-    vertex and every semiring reduces with ``jax.ops.segment_sum/min/max``
-    on the sorted indices (``indices_are_sorted=True``).
+    tested against — float-identical to the pre-backend apps — and as
+    the ``frontier_cap`` path.
 ``pallas``
     The blocked Block-ELL SpMV (``kernels/bsr_spmv``) over
     ``rt.local_bsr()``'s degree-sorted per-machine layout —
@@ -145,9 +153,20 @@ class EdgeBackend:
         def combine_exchanged(sa, x):
             with jax.named_scope("bsp.edge_kernel"):
                 y = combine(sa, x)
-            return exchange(y, sa["rep_slot"], r_pad, mode)
+            return exchange(y, (sa["rep_slot"], sa["slot_vertex"]), r_pad,
+                            mode)
 
         return extras, combine_exchanged
+
+
+#: the apps' edge kernel when the caller names none
+DEFAULT_BACKEND = "segment"
+
+
+def default_backend(frontier_cap: int | None = None) -> str:
+    """The backend an app runs when none is named: :data:`DEFAULT_BACKEND`,
+    or ``scatter`` when a ``frontier_cap`` asks for its frontier path."""
+    return DEFAULT_BACKEND if frontier_cap is None else "scatter"
 
 
 def frontier_entries(rt, changed: np.ndarray) -> np.ndarray:
@@ -175,14 +194,19 @@ def _scatter_prepare_factory(message_dtype: str = "float32",
         wkind = weights
 
         if frontier_cap is None:
+            extras = {"edges": to_device(rt.local_edges),
+                      "edge_valid": to_device(rt.edge_valid)}
+            if wkind == "weight":
+                extras["edge_weight"] = to_device(rt.edge_weight)
+
             def combine(sa, x):
                 src, dst = sa["edges"][:, 0], sa["edges"][:, 1]
                 if wkind == "weight":
                     w_raw = sa["edge_weight"]
-                elif wkind == "unit":
-                    w_raw = jnp.ones_like(sa["edge_weight"])
                 else:
-                    w_raw = jnp.zeros_like(sa["edge_weight"])
+                    w_raw = jnp.full(sa["edge_valid"].shape,
+                                     1.0 if wkind == "unit" else 0.0,
+                                     jnp.float32)
                 w = sr.weights(w_raw, sa["edge_valid"])
                 xm = x.astype(mdt)
                 out = jnp.full(x.shape, sr.zero, dtype=x.dtype)
@@ -194,7 +218,7 @@ def _scatter_prepare_factory(message_dtype: str = "float32",
                     sr.times(w.astype(mdt), xm[dst]).astype(x.dtype))
                 return out
 
-            return {}, combine
+            return extras, combine
 
         # frontier mode: per-vertex ELL of the directed incidence —
         # row v holds v's outgoing (dst, w) entries, padded to the
@@ -251,60 +275,106 @@ def _scatter_prepare_factory(message_dtype: str = "float32",
 
 
 # ---------------------------------------------------------------------------
-# segment: sorted-CSR reduction (jax.ops.segment_* on sorted ids)
+# segment: segmented scan over the destination-sorted incidence (no scatter)
 # ---------------------------------------------------------------------------
 
+@dataclasses.dataclass(frozen=True)
+class SegmentLayout:
+    """Each machine's doubled incidence sorted by output vertex, so that
+    every output vertex's messages are one contiguous run.
+
+    Row ``j`` carries ``x[in_ids[j]] ⊗ w[j]`` into its run.  Invalid rows
+    and the tail padding form a trailing dump run whose ⊗ operand is the
+    semiring's annihilator; it ends at the last row, where every vertex
+    without a message on the machine reads its ⊕ identity.
+    """
+
+    in_ids: np.ndarray      # (p, n) int32: the message's input vertex
+    w: np.ndarray           # (p, n) ⊗ operand, in the message dtype
+    level: np.ndarray       # (p, n) uint8: bit length of the row's rank
+    #                         in its run, i.e. how many scan passes add to it
+    run_end: np.ndarray     # (p, Vmax) int32: last row of each vertex's run
+    passes: int             # scan passes that cover the longest real run
+
+    @classmethod
+    def build(cls, rt, semiring: str, weights: str,
+              message_dtype: str) -> "SegmentLayout":
+        sr = get_semiring(semiring)
+        p, vmax, e = rt.p, rt.vmax, rt.local_edges
+        out = np.concatenate([e[:, :, 1], e[:, :, 0]], axis=1)
+        inc = np.concatenate([e[:, :, 0], e[:, :, 1]], axis=1)
+        valid = np.concatenate([rt.edge_valid, rt.edge_valid], axis=1)
+        w_raw = _edge_operand(rt, weights)
+        w = np.concatenate([w_raw, w_raw], axis=1).astype(np.float32)
+        # the dump run holds at least one row, and rows fill whole lanes
+        n = -(-(out.shape[1] + 1) // 128) * 128
+        pad = ((0, 0), (0, n - out.shape[1]))
+        out = np.pad(np.where(valid, out, vmax), pad, constant_values=vmax)
+        inc = np.pad(np.where(valid, inc, 0), pad)
+        w = np.pad(np.where(valid, w, np.float32(sr.absent)), pad,
+                   constant_values=np.float32(sr.absent))
+        order = np.argsort(out, axis=1, kind="stable")
+        out = np.take_along_axis(out, order, 1)
+        rows = np.arange(n)
+        start = np.ones(out.shape, dtype=bool)
+        start[:, 1:] = out[:, 1:] != out[:, :-1]
+        rank = rows - np.maximum.accumulate(np.where(start, rows, 0), axis=1)
+        level = np.frexp(rank.astype(np.float64))[1].astype(np.uint8)
+        # every vertex reads the dump's last row unless a run of its own
+        # ends earlier (the dump's own end lands in column vmax, dropped)
+        end = np.ones(out.shape, dtype=bool)
+        end[:, :-1] = start[:, 1:]
+        m, j = np.nonzero(end)
+        run_end = np.full((p, vmax + 1), n - 1, dtype=np.int32)
+        run_end[m, out[m, j]] = j
+        return cls(
+            in_ids=np.take_along_axis(inc, order, 1).astype(np.int32),
+            w=np.take_along_axis(w, order, 1).astype(
+                _message_dtype(message_dtype)),
+            level=level, run_end=run_end[:, :vmax],
+            passes=int(level[out < vmax].max(initial=0)))
+
+
+def segmented_scan(vals, level, passes: int, plus):
+    """Inclusive ⊕-scan of ``vals`` that restarts at every run.
+
+    Pass ``k`` adds the row ``2**k`` back to each row at least that far
+    from its run's start (``level > k``), so after it every row holds
+    the ⊕ of up to ``2**(k+1)`` rows of its own run ending there; after
+    ``passes`` passes each run's last row holds the whole run.  Dense
+    shifted elementwise work on the vector unit, log-depth, no scatter.
+    """
+    for k in range(passes):
+        d = 1 << k
+        prev = jnp.pad(vals[:-d], (d, 0))
+        vals = jnp.where(level > k, plus(prev, vals), vals)
+    return vals
+
+
 def _segment_prepare_factory(message_dtype: str = "float32"):
-    def prepare(rt, semiring, weights):
-        return _segment_prepare(rt, semiring, weights,
-                                message_dtype=message_dtype)
+    def prepare(rt, semiring: str, weights: str):
+        sr = get_semiring(semiring)
+        mdt = _message_dtype(message_dtype)
+        lay = rt.layout(("segment", sr.name, weights, str(message_dtype)),
+                        lambda: SegmentLayout.build(rt, sr.name, weights,
+                                                    message_dtype))
+        extras = {"eb_seg_in": to_device(lay.in_ids),
+                  "eb_seg_w": to_device(lay.w),
+                  "eb_seg_level": to_device(lay.level),
+                  "eb_seg_end": to_device(lay.run_end)}
+
+        def combine(sa, x):
+            # low-precision messages, full-precision ⊕: ⊗ in message_dtype,
+            # products back to the state dtype before the reduction
+            vals = sr.times(sa["eb_seg_w"],
+                            x.astype(mdt)[sa["eb_seg_in"]]).astype(x.dtype)
+            scanned = segmented_scan(vals, sa["eb_seg_level"], lay.passes,
+                                     sr.plus)
+            return scanned[sa["eb_seg_end"]]
+
+        return extras, combine
+
     return prepare
-
-
-def _segment_prepare(rt, semiring: str, weights: str,
-                     message_dtype: str = "float32"):
-    sr = get_semiring(semiring)
-    mdt = _message_dtype(message_dtype)
-    vmax = rt.vmax
-    w_raw = _edge_operand(rt, weights)
-
-    # both directions of every edge, output-major: row j of the incidence
-    # receives x[inc_in[j]] ⊗ w[j] into output vertex inc_out[j]
-    inc_out = np.concatenate([rt.local_edges[:, :, 1],
-                              rt.local_edges[:, :, 0]], axis=1)  # (p, 2E)
-    inc_in = np.concatenate([rt.local_edges[:, :, 0],
-                             rt.local_edges[:, :, 1]], axis=1)
-    valid2 = np.concatenate([rt.edge_valid, rt.edge_valid], axis=1)
-    w2 = np.concatenate([w_raw, w_raw], axis=1).astype(np.float32)
-    # invalid rows sort to a trailing dump segment (id = Vmax) and carry
-    # the semiring's annihilator, so they contribute the ⊕ identity
-    inc_out = np.where(valid2, inc_out, vmax).astype(np.int32)
-    w2 = np.where(valid2, w2, np.float32(sr.absent))
-    order = np.argsort(inc_out, axis=1, kind="stable")
-    inc_out = np.take_along_axis(inc_out, order, 1)
-    inc_in = np.take_along_axis(inc_in, order, 1).astype(np.int32)
-    w2 = np.take_along_axis(w2, order, 1)
-    extras = {"eb_seg_out": to_device(inc_out),
-              "eb_seg_in": to_device(inc_in),
-              "eb_seg_w": to_device(w2)}
-
-    def combine(sa, x):
-        # low-precision messages, full-precision ⊕: ⊗ in message_dtype,
-        # products back to the state dtype before the reduction
-        vals = sr.times(sa["eb_seg_w"].astype(mdt),
-                        x.astype(mdt)[sa["eb_seg_in"]]).astype(x.dtype)
-        seg = {"plus_times": jax.ops.segment_sum,
-               "min_plus": jax.ops.segment_min,
-               "or_and": jax.ops.segment_max}[sr.name]
-        y = seg(vals, sa["eb_seg_out"], num_segments=vmax + 1,
-                indices_are_sorted=True)[:vmax]
-        # empty segments come back as the reduction's own identity
-        # (+inf / -inf); clamp the (or, and) case to the semiring zero
-        if sr.name == "or_and":
-            y = jnp.maximum(y, sr.zero)
-        return y.astype(x.dtype)
-
-    return extras, combine
 
 
 # ---------------------------------------------------------------------------
@@ -344,7 +414,7 @@ _REGISTRY = {
             "scatter", "gather-scatter oracle (at[].⊕ per direction)",
             _scatter_prepare_factory(message_dtype, frontier_cap), **kw),
     "segment": lambda message_dtype="float32", **kw: EdgeBackend(
-        "segment", "sorted-CSR reduction (jax.ops.segment_*)",
+        "segment", "segmented scan over the destination-sorted incidence",
         _segment_prepare_factory(message_dtype), **kw),
     "pallas": lambda block_size=128, interpret=None,
         message_dtype="float32", **kw: EdgeBackend(
@@ -356,8 +426,9 @@ _REGISTRY = {
 BACKENDS = tuple(_REGISTRY)
 
 
-def get_backend(name, **opts) -> EdgeBackend:
-    """Resolve a backend by name (``EdgeBackend`` passes through).
+def get_backend(name=None, **opts) -> EdgeBackend:
+    """Resolve a backend by name (``EdgeBackend`` passes through, ``None``
+    is :func:`default_backend`).
 
     ``opts`` are backend-specific: every backend takes ``message_dtype``
     (default ``"float32"``; ``"bfloat16"`` is the low-precision message
@@ -368,6 +439,8 @@ def get_backend(name, **opts) -> EdgeBackend:
     """
     if isinstance(name, EdgeBackend):
         return name
+    if name is None:
+        name = default_backend(opts.get("frontier_cap"))
     try:
         factory = _REGISTRY[name]
     except KeyError:

@@ -11,7 +11,8 @@ runs under
 The replica exchange is the only cross-machine communication: a psum (or
 pmin/pmax) over an (R+1,)-sized buffer — fixed shape, proportional to the
 partition's replication, which is exactly the quantity the paper's TC comm
-term charges for.
+term charges for.  Each machine fills and reads the buffer by gathers
+through its replica table (``rep_slot`` and ``slot_vertex``).
 
 Superstep contract: ``superstep(state, static) -> (state, active_count)``
 with per-machine (rank-reduced) arrays, using ``exchange`` for sync.
@@ -63,32 +64,32 @@ def _extreme(dtype, sign: int):
     return dt.type(jnp.inf if sign > 0 else -jnp.inf)
 
 
-def exchange(partial: jnp.ndarray, rep_slot: jnp.ndarray, r_pad: int,
+def exchange(partial: jnp.ndarray, replicas, r_pad: int,
              mode: str = "sum") -> jnp.ndarray:
     """Synchronize replicated-vertex values across machines.
 
     partial: (Vmax,) this machine's local value per local vertex.
+    replicas: ``(rep_slot, slot_vertex)``: each local vertex's slot in
+    the (r_pad + 1,) replica table, -1 if the vertex lives on this
+    machine alone, and each slot's local vertex, -1 where this machine
+    holds none.  The table is read by gathers both ways, so the
+    exchange lowers to no scatter.
     Returns (Vmax,) with replicated entries replaced by the cross-machine
     combination (sum / min / max); non-replicated entries pass through.
     """
+    rep_slot, slot_vertex = replicas
+    reduce, identity = {
+        "sum": (jax.lax.psum, partial.dtype.type(0)),
+        "min": (jax.lax.pmin, _extreme(partial.dtype, +1)),
+        "max": (jax.lax.pmax, _extreme(partial.dtype, -1)),
+    }.get(mode, (None, None))
+    if reduce is None:
+        raise ValueError(mode)
     with jax.named_scope("bsp.exchange"):
+        buf = jnp.where(slot_vertex >= 0,
+                        partial[jnp.maximum(slot_vertex, 0)], identity)
+        tot = reduce(buf, MACHINES)
         slot = jnp.where(rep_slot >= 0, rep_slot, r_pad)
-        if mode == "sum":
-            buf = jnp.zeros(r_pad + 1, dtype=partial.dtype)
-            buf = buf.at[slot].add(jnp.where(rep_slot >= 0, partial, 0))
-            tot = jax.lax.psum(buf, MACHINES)
-        elif mode == "min":
-            hi = _extreme(partial.dtype, +1)
-            buf = jnp.full(r_pad + 1, hi, dtype=partial.dtype)
-            buf = buf.at[slot].min(jnp.where(rep_slot >= 0, partial, hi))
-            tot = jax.lax.pmin(buf, MACHINES)
-        elif mode == "max":
-            lo = _extreme(partial.dtype, -1)
-            buf = jnp.full(r_pad + 1, lo, dtype=partial.dtype)
-            buf = buf.at[slot].max(jnp.where(rep_slot >= 0, partial, lo))
-            tot = jax.lax.pmax(buf, MACHINES)
-        else:
-            raise ValueError(mode)
         return jnp.where(rep_slot >= 0, tot[slot], partial)
 
 
@@ -251,7 +252,8 @@ def make_fused_runner(superstep: Callable, static, *,
         def step_once(c):
             st, buf, t, _ = c
             new_st, act = step_fn(st)
-            return (new_st, buf.at[t].set(act), t + 1,
+            buf = jax.lax.dynamic_update_index_in_dim(buf, act, t, 0)
+            return (new_st, buf, t + 1,
                     done_of(st, new_st, act))
 
         st, buf, t, done = jax.lax.while_loop(

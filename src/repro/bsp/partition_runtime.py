@@ -10,11 +10,12 @@ Every machine gets the same padded shapes (shard_map/vmap require it):
 * ``global_degree``:    (p, Vmax) degree of the vertex in G (pad: 1)
 * ``weighted_degree``:  (p, Vmax) sum of incident edge weights (pad: 1)
 * ``rep_slot``:         (p, Vmax) slot into the replica exchange table,
-                        -1 if the vertex lives on a single machine.
+                        -1 if the vertex lives on a single machine
+                        (``slot_vertex`` is its inverse).
 
 The replica table has one slot per vertex present on ≥2 machines; the BSP
-exchange is a psum/pmin over a (R+1,) buffer (last slot = scatter dump for
-non-replicated lanes).
+exchange is a psum/pmin over a (R+1,) buffer (the last slot is never a
+vertex's: non-replicated lanes read it and discard what they read).
 
 :meth:`PartitionRuntime.local_bsr` additionally exposes each machine's
 edge set as a blocked local adjacency (:class:`LocalBSR`) — the layout the
@@ -188,8 +189,30 @@ class PartitionRuntime:
         return self.local_edges.shape[1]
 
     @functools.cached_property
-    def _bsr_cache(self) -> dict:
+    def slot_vertex(self) -> np.ndarray:
+        """(p, R + 1) int32, ``rep_slot`` inverted: the local vertex in
+        each replica slot, -1 where the machine holds none (R =
+        ``max(1, num_replicas)``; the last slot never has one)."""
+        out = np.full((self.p, max(1, self.num_replicas) + 1), -1, np.int32)
+        m, v = np.nonzero(self.rep_slot >= 0)
+        out[m, self.rep_slot[m, v]] = v
+        return out
+
+    @functools.cached_property
+    def _layouts(self) -> dict:
         return {}
+
+    def layout(self, key: tuple, build):
+        """``build()``, once per ``key`` on this runtime.
+
+        The edge-kernel backends keep their host layouts here (the
+        partition is static, so a layout is built once per runtime, not
+        per app call); each build counts one ``bsp.layout_builds``.
+        """
+        if key not in self._layouts:
+            obs.count("bsp.layout_builds")
+            self._layouts[key] = build()
+        return self._layouts[key]
 
     def local_bsr(self, *, block_size: int = 128,
                   semiring: str = "plus_times", weights: str = "weight",
@@ -204,12 +227,11 @@ class PartitionRuntime:
         halves the blocks' footprint and feeds the low-precision message
         path without touching the float32 entry).
         """
-        key = (int(block_size), str(semiring), str(weights), str(dtype))
-        if key not in self._bsr_cache:
-            self._bsr_cache[key] = LocalBSR.build(
-                self, block_size=block_size, semiring=semiring,
-                weights=weights, dtype=dtype)
-        return self._bsr_cache[key]
+        key = ("bsr", int(block_size), str(semiring), str(weights),
+               str(dtype))
+        return self.layout(key, lambda: LocalBSR.build(
+            self, block_size=block_size, semiring=semiring,
+            weights=weights, dtype=dtype))
 
     @classmethod
     def create(cls, source=None, *, assign=None, p=None, cluster=None,

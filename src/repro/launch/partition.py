@@ -23,9 +23,9 @@ shards of a finalized assignment directory and republish its meta.
 ``--pagerank`` closes the loop: it packs the partition it just built into
 the BSP runtime and runs distributed PageRank supersteps on it, through a
 selectable **edge-kernel backend** (``--backend``, see
-``repro.bsp.backends``): ``scatter`` is the gather-scatter oracle,
-``segment`` the sorted-CSR reduction (``jax.ops.segment_*``),
-``pallas`` the blocked Block-ELL semiring SpMV (compiled on TPU,
+``repro.bsp.backends``): ``segment`` (the default) reduces the
+destination-sorted incidence by a segmented scan, ``scatter`` is the
+gather-scatter oracle, ``pallas`` the blocked Block-ELL semiring SpMV (compiled on TPU,
 interpreter on CPU).  ``--fused`` runs the whole
 iteration as one on-device dispatch (``run_bsp_fused``) instead of one
 dispatch + host sync per superstep; ``--tol`` additionally stops once
@@ -128,12 +128,12 @@ def main(argv=None):
                     help="after partitioning, pack the BSP runtime and "
                          "run distributed PageRank on the partition")
     ap.add_argument("--pagerank-iters", type=int, default=20)
-    ap.add_argument("--backend", default="scatter",
+    ap.add_argument("--backend", default="segment",
                     choices=EDGE_BACKENDS,
-                    help="edge-kernel backend for --pagerank: scatter "
-                         "(gather-scatter oracle), segment (sorted-CSR "
-                         "reduction), pallas (blocked Block-ELL "
-                         "semiring SpMV)")
+                    help="edge-kernel backend for --pagerank: segment "
+                         "(segmented scan, the default), scatter "
+                         "(gather-scatter oracle), pallas (blocked "
+                         "Block-ELL semiring SpMV)")
     ap.add_argument("--fused", action="store_true",
                     help="--pagerank: run the whole iteration as one "
                          "on-device dispatch (run_bsp_fused) instead of "

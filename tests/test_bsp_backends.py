@@ -5,11 +5,15 @@ whichever backend combines their edge messages — bitwise for the
 (min, +)/(or, and) apps (min/max reassociate exactly), within 1e-5 for
 (+, ×) (each backend sums a vertex's messages in its own order) — under
 vmap here and under a real 8-device shard_map mesh in the subprocess
-test.  Weighted PageRank is pinned against a NetworkX-free dense oracle.
+test.  ``scatter`` is the oracle the others are compared with, named
+explicitly; ``segment`` (the default) is pinned against it per semiring.
+Weighted PageRank is pinned against a NetworkX-free dense oracle.
 """
 import subprocess
 import sys
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -18,6 +22,8 @@ from repro.bsp import (PartitionRuntime, bfs, build_app, connected_components,
 from repro.core import scaled_paper_cluster, windgp
 from repro.data import graph500, rmat
 from repro.bsp import RunOptions
+from repro.bsp.backends import DEFAULT_BACKEND
+from repro.kernels.bsr_spmv import get_semiring
 
 PALLAS_OPTS = {"block_size": 16}
 OTHER = (("segment", {}), ("pallas", PALLAS_OPTS))
@@ -59,21 +65,21 @@ def dense_weighted_pagerank(g, w, num_iters=20, damping=0.85):
 class TestCrossBackend:
     def test_pagerank_close(self, part):
         _, _, rt = part
-        base, _ = pagerank(rt, num_iters=15)
+        base, _ = pagerank(rt, num_iters=15, backend="scatter")
         for be, opts in OTHER:
             got, _ = pagerank(rt, num_iters=15, backend=be, **opts)
             np.testing.assert_allclose(got, base, rtol=1e-5, atol=1e-5)
 
     def test_sssp_bitwise(self, part):
         _, _, rt = part
-        base, _ = sssp(rt, source=0, num_iters=20)
+        base, _ = sssp(rt, source=0, num_iters=20, backend="scatter")
         for be, opts in OTHER:
             got, _ = sssp(rt, source=0, num_iters=20, backend=be, **opts)
             np.testing.assert_array_equal(got, base)
 
     def test_bfs_bitwise(self, part):
         g, _, rt = part
-        base, actives = bfs(rt, source=1, num_iters=20)
+        base, actives = bfs(rt, source=1, num_iters=20, backend="scatter")
         # the (or, and) frontier rewrite still equals the min-plus oracle
         expect = ref.bfs(g, source=1, num_iters=20)
         m = ~np.isinf(expect)
@@ -85,7 +91,7 @@ class TestCrossBackend:
 
     def test_cc_bitwise(self, part):
         _, _, rt = part
-        base, _ = connected_components(rt, num_iters=20)
+        base, _ = connected_components(rt, num_iters=20, backend="scatter")
         for be, opts in OTHER:
             got, _ = connected_components(rt, num_iters=20, backend=be,
                                           **opts)
@@ -113,7 +119,7 @@ class TestCrossBackend:
         for app in ("pagerank", "sssp", "bfs", "cc"):
             spec = build_app(rt, app, backend="segment")
             assert spec.name in (app, "sssp")
-            assert "eb_seg_out" in spec.static
+            assert "eb_seg_in" in spec.static and "edges" not in spec.static
         with pytest.raises(ValueError, match="unknown BSP app"):
             build_app(rt, "betweenness")
 
@@ -133,6 +139,146 @@ def test_pagerank_per_vertex_relative_error(backend):
     assert np.max(np.abs(got - want) / want) <= 1e-4
 
 
+# ---------------------------------------------------------------------------
+# segment, the default: a segmented scan checked against the scatter oracle
+# ---------------------------------------------------------------------------
+
+#: semiring -> (the weight kind its app combines with, the app)
+SEMIRINGS = {"plus_times": ("weight", "pagerank"),
+             "min_plus": ("weight", "sssp"),
+             "or_and": ("unit", "bfs")}
+APP_CALLS = {"pagerank": (pagerank, dict(num_iters=15)),
+             "sssp": (sssp, dict(source=0, num_iters=20)),
+             "bfs": (bfs, dict(source=1, num_iters=20)),
+             "cc": (connected_components, dict(num_iters=20))}
+
+
+@pytest.fixture(scope="module")
+def g500_12():
+    g = graph500(12, seed=0)
+    cl = scaled_paper_cluster(2, 6, g.num_edges)
+    return PartitionRuntime.create(source=g, method="windgp", cluster=cl)
+
+
+def _messages(semiring, shape, seed=0):
+    """Vertex values with every kind of entry the semiring meets: for
+    (min, +) half are +inf (no message), for (or, and) a 0/1 frontier."""
+    rng = np.random.default_rng(seed)
+    if semiring == "plus_times":
+        return rng.random(shape).astype(np.float32)
+    if semiring == "min_plus":
+        return np.where(rng.random(shape) < 0.5, np.inf,
+                        rng.random(shape) * 10).astype(np.float32)
+    return (rng.random(shape) < 0.3).astype(np.float32)
+
+
+def _combine(rt, backend, semiring, x):
+    """One backend's (p, Vmax) combine of ``x``, before the exchange."""
+    extras, combine = get_backend(backend).prepare(
+        rt, semiring, SEMIRINGS[semiring][0])
+    return np.asarray(jax.jit(jax.vmap(combine))(extras, jnp.asarray(x)))
+
+
+def _local_messages(rt):
+    """(p, Vmax) bool: the vertex has a valid incident edge on the
+    machine, so it receives at least one message there."""
+    has = np.zeros((rt.p, rt.vmax), dtype=bool)
+    for i in range(rt.p):
+        e = rt.local_edges[i][rt.edge_valid[i]]
+        has[i, e[:, 0]] = has[i, e[:, 1]] = True
+    return has
+
+
+@pytest.mark.parametrize("app", list(APP_CALLS))
+def test_the_default_backend_is_segment(part, app):
+    """No backend named: ``segment``; a ``frontier_cap`` alone keeps
+    ``scatter``, whose frontier path it asks for."""
+    _, _, rt = part
+    assert DEFAULT_BACKEND == "segment"
+    assert get_backend().name == "segment"
+    assert RunOptions().backend == "segment"
+    assert RunOptions(frontier_cap=8).backend == "scatter"
+    assert get_backend(frontier_cap=8).name == "scatter"
+    spec = build_app(rt, app)
+    assert "eb_seg_in" in spec.static and "edges" not in spec.static
+    fn, kw = APP_CALLS[app]
+    got, _ = fn(rt, **kw)
+    want, _ = fn(rt, backend="segment", **kw)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("semiring", list(SEMIRINGS))
+def test_segment_matches_scatter_per_vertex(g500_12, semiring):
+    """Bitwise for min/max; for (+, ×) each vertex's sum within 1e-6
+    relative of the oracle's (another summation order of the same
+    float32 products, never another vertex's)."""
+    rt = g500_12
+    x = _messages(semiring, (rt.p, rt.vmax))
+    want = _combine(rt, "scatter", semiring, x)
+    got = _combine(rt, "segment", semiring, x)
+    if semiring != "plus_times":
+        np.testing.assert_array_equal(got, want)
+        return
+    assert not np.array_equal(got, 0)
+    rel = np.abs(got - want) / np.maximum(np.abs(want), 1e-30)
+    assert rel.max() <= 1e-6
+
+
+@pytest.mark.parametrize("semiring", list(SEMIRINGS))
+def test_vertices_without_local_messages_get_the_zero(g500_12, semiring):
+    rt = g500_12
+    none = ~_local_messages(rt)
+    assert none.any()                   # padding lanes at least
+    x = _messages(semiring, (rt.p, rt.vmax), seed=1)
+    got = _combine(rt, "segment", semiring, x)
+    np.testing.assert_array_equal(got[none],
+                                  get_semiring(semiring).zero)
+
+
+@pytest.fixture(scope="module")
+def idle_machine():
+    """Five machines, the last of which gets no edge at all."""
+    g = rmat(8, seed=4)
+    assign = (np.arange(g.num_edges) % 4).astype(np.int64)
+    return g, PartitionRuntime.build(g, assign, 5)
+
+
+@pytest.mark.parametrize("semiring", list(SEMIRINGS))
+def test_a_machine_with_no_valid_edge(idle_machine, semiring):
+    g, rt = idle_machine
+    assert not rt.edge_valid[-1].any()
+    x = _messages(semiring, (rt.p, rt.vmax), seed=2)
+    got = _combine(rt, "segment", semiring, x)
+    np.testing.assert_array_equal(got[-1], get_semiring(semiring).zero)
+    want = _combine(rt, "scatter", semiring, x)
+    if semiring == "plus_times":
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+    else:
+        np.testing.assert_array_equal(got, want)
+    app = SEMIRINGS[semiring][1]
+    fn, kw = APP_CALLS[app]
+    seg, _ = fn(rt, **kw)
+    sca, _ = fn(rt, backend="scatter", **kw)
+    if app == "pagerank":
+        np.testing.assert_allclose(seg, ref.pagerank(g, 15), rtol=2e-4)
+        np.testing.assert_allclose(seg, sca, rtol=1e-5)
+    else:
+        np.testing.assert_array_equal(seg, sca)
+
+
+@pytest.mark.parametrize("app", list(APP_CALLS))
+def test_default_stepwise_and_fused_runners_agree(part, app):
+    _, _, rt = part
+    fn, kw = APP_CALLS[app]
+    a, acts_a = fn(rt, **kw)
+    b, acts_b = fn(rt, fused=True, chunk=4, **kw)
+    if app == "pagerank":
+        np.testing.assert_allclose(a, b, atol=1e-6)
+    else:
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(acts_a[:len(acts_b)], acts_b)
+
+
 class TestWeightedPageRank:
     def test_matches_dense_oracle(self, weighted_part):
         """The `edge_weight`-vs-`edge_valid` bug regression: weights must
@@ -147,7 +293,7 @@ class TestWeightedPageRank:
 
     def test_weighted_across_backends(self, weighted_part):
         _, _, rt = weighted_part
-        base, _ = pagerank(rt, num_iters=15)
+        base, _ = pagerank(rt, num_iters=15, backend="scatter")
         for be, opts in OTHER:
             got, _ = pagerank(rt, num_iters=15, backend=be, **opts)
             np.testing.assert_allclose(got, base, rtol=1e-5, atol=1e-5)
@@ -186,10 +332,10 @@ cl = scaled_paper_cluster(2, 6, g.num_edges)   # p = 8 machines = 8 devices
 r = windgp(g, cl, t0=2)
 rt = PartitionRuntime.build(g, r.assign, cl.p)
 mesh = jax.make_mesh((8,), ("machines",))
-pr0, _ = pagerank(rt, num_iters=8)
-d0, _ = sssp(rt, source=0, num_iters=12)
-b0, _ = bfs(rt, source=1, num_iters=12)
-c0, _ = connected_components(rt, num_iters=12)
+pr0, _ = pagerank(rt, num_iters=8, backend="scatter")
+d0, _ = sssp(rt, source=0, num_iters=12, backend="scatter")
+b0, _ = bfs(rt, source=1, num_iters=12, backend="scatter")
+c0, _ = connected_components(rt, num_iters=12, backend="scatter")
 for be, kw in (("scatter", {}), ("segment", {}),
                ("pallas", {"block_size": 32})):
     pr, _ = pagerank(rt, num_iters=8, mesh=mesh, backend=be, **kw)

@@ -166,12 +166,13 @@ class TestExchangeDtypes:
     def test_min_max_roundtrip(self, dtype):
         # two machines; vertex 0 replicated in slot 0, vertex 1 private
         rep_slot = jnp.asarray(np.array([[0, -1], [0, -1]], np.int32))
+        slot_vertex = jnp.asarray(np.array([[0, -1], [0, -1]], np.int32))
         vals = jnp.asarray(np.array([[5, 7], [3, 9]], dtype))
-        lo = jax.vmap(lambda v, s: exchange(v, s, 1, "min"),
-                      axis_name=MACHINES)(vals, rep_slot)
+        lo = jax.vmap(lambda v, s, t: exchange(v, (s, t), 1, "min"),
+                      axis_name=MACHINES)(vals, rep_slot, slot_vertex)
         np.testing.assert_array_equal(np.asarray(lo), [[3, 7], [3, 9]])
-        hi = jax.vmap(lambda v, s: exchange(v, s, 1, "max"),
-                      axis_name=MACHINES)(vals, rep_slot)
+        hi = jax.vmap(lambda v, s, t: exchange(v, (s, t), 1, "max"),
+                      axis_name=MACHINES)(vals, rep_slot, slot_vertex)
         np.testing.assert_array_equal(np.asarray(hi), [[5, 7], [5, 9]])
         assert np.asarray(hi).dtype == dtype
 
@@ -205,6 +206,30 @@ class TestStaticIsAnArgument:
                 spec.state, 10)
         graph = min(a.size for a in jax.tree.leaves(spec.static))
         assert _largest_constant(lowered.as_text()) < graph
+
+
+class TestDefaultSuperstepHasNoScatter:
+    """The default edge kernel reduces by a segmented scan and the
+    exchange fills its table by gathers: XLA:TPU runs a scatter as
+    element-wise random access, so the lowered default superstep, alone
+    or in the fused loop, holds none — under each semiring."""
+
+    @pytest.mark.parametrize("runner", ["step", "fused"])
+    @pytest.mark.parametrize("app", ["pagerank", "sssp", "bfs"])
+    def test_lowered_default_superstep(self, part, runner, app):
+        _, rt = part
+        spec = build_app(rt, app)
+        if runner == "step":
+            lowered = make_step(spec.superstep, spec.static).lower(
+                spec.state)
+        else:
+            lowered = make_fused_runner(spec.superstep, spec.static).lower(
+                spec.state, 10)
+        text = lowered.as_text()
+        assert "eb_seg_in" in spec.static
+        assert "scatter" not in text
+        graph = min(a.size for a in jax.tree.leaves(spec.static))
+        assert _largest_constant(text) < graph
 
 
 MESH_FUSED_SCRIPT = r"""

@@ -9,6 +9,7 @@ import pytest
 
 from repro import obs
 from repro.bsp import PartitionRuntime, pagerank, sssp
+from repro.bsp.backends import SegmentLayout
 from repro.core import scaled_paper_cluster, windgp
 from repro.data import rmat
 
@@ -176,10 +177,17 @@ def rt():
     return PartitionRuntime.create(g, method="windgp", cluster=cl, t0=2)
 
 
-def _static_bytes(rt):
+def _vertex_bytes(rt):
     return sum(a.nbytes for a in (
-        rt.local_edges, rt.edge_valid, rt.edge_weight, rt.vertex_valid,
-        rt.global_degree, rt.weighted_degree, rt.rep_slot))
+        rt.vertex_valid, rt.weighted_degree, rt.rep_slot, rt.slot_vertex))
+
+
+def _static_bytes(rt, semiring):
+    """What a default call uploads besides its state: the per-vertex
+    arrays and the segment layout, no edge array."""
+    lay = SegmentLayout.build(rt, semiring, "weight", "float32")
+    return _vertex_bytes(rt) + sum(a.nbytes for a in (
+        lay.in_ids, lay.w, lay.level, lay.run_end))
 
 
 def _one_call():
@@ -194,12 +202,14 @@ def test_a_stepwise_call_is_prepare_supersteps_gather(rt, app):
     if app == "pagerank":
         pagerank(rt, num_iters=n)
         state_bytes = rt.vertex_valid.nbytes
+        semiring = "plus_times"
     else:
         sssp(rt, source=1, num_iters=n)
         state_bytes = rt.p * rt.vmax * (4 + 1)       # dist, changed
+        semiring = "min_plus"
     req = _one_call()
     assert req.root.attrs == {"app": app, "runner": "stepwise",
-                              "backend": "scatter", "supersteps": n,
+                              "backend": "segment", "supersteps": n,
                               "req": req.id}
     top = [s.name for s in req.children(req.root)]
     assert top == ["bsp.prepare"] + ["bsp.superstep"] * n + ["bsp.gather"]
@@ -210,7 +220,8 @@ def test_a_stepwise_call_is_prepare_supersteps_gather(rt, app):
         assert [c.name for c in req.children(s)] == ["bsp.sync"]
     assert req.counters["bsp.retraces"] == 1
     assert req.counters["bsp.host_syncs"] == n + 1
-    assert req.counters["bsp.h2d_bytes"] == _static_bytes(rt) + state_bytes
+    assert req.counters["bsp.h2d_bytes"] == \
+        _static_bytes(rt, semiring) + state_bytes
 
 
 def test_a_fused_call_dispatches_once_and_syncs_three_times(rt):
@@ -222,7 +233,31 @@ def test_a_fused_call_dispatches_once_and_syncs_three_times(rt):
     assert req.counters["bsp.retraces"] == 1
     assert req.counters["bsp.host_syncs"] == 3      # ts, bufs, the answer
     assert req.counters["bsp.h2d_bytes"] == \
-        _static_bytes(rt) + rt.vertex_valid.nbytes
+        _static_bytes(rt, "plus_times") + rt.vertex_valid.nbytes
+
+
+def test_the_layout_is_built_on_the_first_call_only():
+    g = rmat(7, seed=6)
+    cl = scaled_paper_cluster(1, 3, g.num_edges)
+    rt = PartitionRuntime.create(g, method="windgp", cluster=cl, t0=2)
+    for _ in range(2):
+        pagerank(rt, num_iters=2)
+    calls = obs.requests()
+    assert [r.counters.get("bsp.layout_builds", 0) for r in calls] == [1, 0]
+    assert obs.totals()["counts"]["bsp.layout_builds"] == 1
+
+
+def test_a_default_call_uploads_no_edge_array(rt):
+    """``edges``, ``edge_weight`` and ``edge_valid`` go up only for the
+    backend that reads them."""
+    edge_bytes = sum(a.nbytes for a in (
+        rt.local_edges, rt.edge_valid, rt.edge_weight))
+    state_bytes = rt.vertex_valid.nbytes
+    pagerank(rt, num_iters=1)
+    pagerank(rt, num_iters=1, backend="scatter")
+    default, scatter = [r.counters["bsp.h2d_bytes"] for r in obs.requests()]
+    assert default == _static_bytes(rt, "plus_times") + state_bytes
+    assert scatter == _vertex_bytes(rt) + edge_bytes + state_bytes
 
 
 def test_every_app_call_traces_its_superstep_once(rt):
