@@ -42,7 +42,9 @@ Every app wrapper runs on either engine runner: the per-step oracle
 ``tol=`` → ``run_bsp_fused``), and backend opts such as
 ``message_dtype="bfloat16"`` flow through ``**backend_opts``.  Each call
 is one ``bsp.call`` request of ``repro.obs``: ``bsp.prepare``, the
-supersteps, ``bsp.gather``.
+supersteps, ``bsp.gather``.  With ``mesh=`` the prepare uploads each
+machine's rows straight to its own device, so the supersteps move no
+array between devices but the exchange's all-reduce.
 """
 from __future__ import annotations
 
@@ -55,7 +57,7 @@ import numpy as np
 
 from .. import obs
 from .backends import BACKENDS, MESSAGE_DTYPES, default_backend, get_backend
-from .engine import run_bsp, run_bsp_fused, to_device, to_host
+from .engine import placed_on, run_bsp, run_bsp_fused, to_device, to_host
 from .partition_runtime import PartitionRuntime
 
 #: apps whose state is monotone under the semiring: they already early-exit
@@ -220,12 +222,13 @@ def _call(app: str, rt, opts: RunOptions, num_steps: int, mesh,
           build: Callable[..., "AppSpec"], *args, **kw):
     """One app call as a ``bsp.call`` request: prepare (``build(*args,
     **kw)`` with the options' backend: the backend's layout, the static
-    tree and the initial state, uploaded), run, gather.  Returns
-    ``(global result, actives)``."""
+    tree and the initial state, uploaded to the mesh's devices if one is
+    given), run, gather.  Returns ``(global result, actives)``."""
     runner = "fused" if opts.fused or opts.tol is not None else "stepwise"
     with obs.request("bsp.call", app=app, runner=runner,
-                     backend=opts.backend, supersteps=num_steps):
-        with obs.span("bsp.prepare"):
+                     backend=opts.backend, supersteps=num_steps,
+                     devices=1 if mesh is None else mesh.size):
+        with obs.span("bsp.prepare"), placed_on(mesh):
             spec = build(*args, backend=opts.backend,
                          **opts.backend_opts(), **kw)
         out, actives = _run(spec, num_steps, mesh=mesh, fused=opts.fused,
@@ -371,7 +374,7 @@ def build_bfs(rt: PartitionRuntime, source: int, *, backend=None,
     holders = np.nonzero(rt.local_vertex_gid == source)
     dist0[holders] = 0.0
     state = {"dist": to_device(dist0),
-             "step": jnp.zeros(rt.p, dtype=jnp.float32)}
+             "step": to_device(np.zeros(rt.p, dtype=np.float32))}
     fin = lambda rt, out: rt.gather_global(to_host(out["dist"]),
                                            fill=np.inf)
     return AppSpec("bfs", superstep, state, static, eb.check_rep, fin)

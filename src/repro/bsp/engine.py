@@ -32,22 +32,34 @@ Two runners iterate that contract:
 On the host every stepwise superstep is a ``bsp.superstep`` span
 (``repro.obs``); every device→host read goes through :func:`to_host` and
 every upload of the app path through :func:`to_device`, so the recorder
-counts them.
+counts them.  On a mesh an upload goes from the host straight to the
+devices (:func:`placed_on`), and both runners count the replica table
+each superstep all-reduces (``bsp.exchange_bytes``).
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import obs
 
 
 MACHINES = "machines"
+
+
+#: where :func:`to_device` puts an upload: JAX's default device (None),
+#: or a machine-stacked sharding while a call on a mesh prepares
+_placement = contextvars.ContextVar("bsp_placement", default=None)
+#: while a mesh runner traces its superstep: the list each
+#: :func:`exchange` adds its table's bytes per machine to
+_exchanged = contextvars.ContextVar("bsp_exchanged", default=None)
 
 
 def _extreme(dtype, sign: int):
@@ -88,16 +100,32 @@ def exchange(partial: jnp.ndarray, replicas, r_pad: int,
     with jax.named_scope("bsp.exchange"):
         buf = jnp.where(slot_vertex >= 0,
                         partial[jnp.maximum(slot_vertex, 0)], identity)
+        if (tally := _exchanged.get()) is not None:
+            tally.append(buf.size * buf.dtype.itemsize)
         tot = reduce(buf, MACHINES)
         slot = jnp.where(rep_slot >= 0, rep_slot, r_pad)
         return jnp.where(rep_slot >= 0, tot[slot], partial)
+
+
+@contextlib.contextmanager
+def placed_on(mesh: Mesh | None):
+    """Inside the block, :func:`to_device` puts row ``i`` of each
+    machine-stacked array on device ``i`` of ``mesh``, straight from the
+    host; with no mesh it uploads to JAX's default device."""
+    token = _placement.set(
+        None if mesh is None else NamedSharding(mesh, P(MACHINES)))
+    try:
+        yield
+    finally:
+        _placement.reset(token)
 
 
 def to_device(x) -> jax.Array:
     """Host→device upload of one array, counted in ``bsp.h2d_bytes``."""
     x = np.asarray(x)
     obs.count("bsp.h2d_bytes", x.nbytes)
-    return jnp.asarray(x)
+    sharding = _placement.get()
+    return jnp.asarray(x) if sharding is None else jax.device_put(x, sharding)
 
 
 def to_host(x) -> np.ndarray:
@@ -129,6 +157,25 @@ def _bind_static(jitted, static, make_args=lambda *a: a):
     return run
 
 
+class _Exchanging:
+    """A superstep that notes, each time JAX traces it, the bytes its
+    exchanges all-reduce per machine (``nbytes``): what one superstep
+    sends across chips on a mesh."""
+
+    def __init__(self, superstep: Callable):
+        self.superstep, self.nbytes = superstep, 0
+
+    def __call__(self, state, static):
+        tally = []
+        token = _exchanged.set(tally)
+        try:
+            out = self.superstep(state, static)
+        finally:
+            _exchanged.reset(token)
+        self.nbytes = sum(tally)
+        return out
+
+
 def make_step(superstep: Callable, static, *, mesh: Mesh | None = None,
               check_rep: bool = True):
     """Compile one BSP superstep: state -> (state, (p,) active counts).
@@ -136,7 +183,8 @@ def make_step(superstep: Callable, static, *, mesh: Mesh | None = None,
     The returned callable also offers ``.lower(state)``.
     ``check_rep=False`` disables shard_map's replication check — required
     when the superstep body contains ops without a replication rule
-    (``pallas_call``; the edge-kernel backends declare this).
+    (``pallas_call``; the edge-kernel backends declare this).  On a mesh
+    each call counts the superstep's ``bsp.exchange_bytes``.
     """
     if mesh is None:
         def vstep(state, static):
@@ -146,6 +194,7 @@ def make_step(superstep: Callable, static, *, mesh: Mesh | None = None,
         return _bind_static(jax.jit(vstep), static)
 
     state_spec_of = lambda tree: jax.tree.map(lambda _: P(MACHINES), tree)
+    superstep = _Exchanging(superstep)
 
     def step(state, static):
         _count_trace()
@@ -162,7 +211,15 @@ def make_step(superstep: Callable, static, *, mesh: Mesh | None = None,
             out_specs=(state_spec_of(state), P(MACHINES)),
             check_vma=check_rep)(state, static)
 
-    return _bind_static(jax.jit(step), static)
+    dispatch = _bind_static(jax.jit(step), static)
+
+    def run(state):
+        out = dispatch(state)
+        obs.count("bsp.exchange_bytes", superstep.nbytes)
+        return out
+
+    run.lower = dispatch.lower
+    return run
 
 
 def _num_machines(state) -> int:
@@ -238,7 +295,8 @@ def make_fused_runner(superstep: Callable, static, *,
         num_chunks = -(-num_steps // chunk)
         limits = np.full(num_chunks, chunk, dtype=np.int32)
         limits[-1] = num_steps - chunk * (num_chunks - 1)
-        return state, jnp.asarray(limits)
+        # from the host: on a mesh it goes to every device from there
+        return state, limits
 
     def chunk_body(step_fn, done_of, new_buf, carry, limit):
         """One scan step: while_loop over ≤ chunk supersteps, gated."""
@@ -295,6 +353,7 @@ def make_fused_runner(superstep: Callable, static, *,
     # shard_map: the fused loop runs rank-reduced per device; the gate
     # reduces with a collective so every device agrees on the predicate
     state_spec_of = lambda tree: jax.tree.map(lambda _: P(MACHINES), tree)
+    superstep = _Exchanging(superstep)
 
     def sharded(state_b, limits, static_b):
         st = jax.tree.map(lambda a: a[0], state_b)
@@ -339,6 +398,7 @@ def make_fused_runner(superstep: Callable, static, *,
             return state, np.zeros((0, p))
         state, bufs, ts = dispatch(state, num_steps)
         steps = int(to_host(ts)[0].sum())
+        obs.count("bsp.exchange_bytes", steps * superstep.nbytes)
         # bufs: (p, num_chunks, chunk) -> (num_chunks*chunk, p), trimmed
         actives = to_host(bufs).transpose(1, 2, 0).reshape(-1, p)[:steps]
         return state, actives

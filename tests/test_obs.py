@@ -210,7 +210,7 @@ def test_a_stepwise_call_is_prepare_supersteps_gather(rt, app):
     req = _one_call()
     assert req.root.attrs == {"app": app, "runner": "stepwise",
                               "backend": "segment", "supersteps": n,
-                              "req": req.id}
+                              "devices": 1, "req": req.id}
     top = [s.name for s in req.children(req.root)]
     assert top == ["bsp.prepare"] + ["bsp.superstep"] * n + ["bsp.gather"]
     steps = req.named("bsp.superstep")
