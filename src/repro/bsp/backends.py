@@ -28,7 +28,11 @@ Backends:
     log-depth shifted passes that restart at every run
     (:func:`segmented_scan`), and reads each run's last row.  XLA lowers
     a sorted ``segment_sum`` to a scatter on the TPU; this lowers to
-    dense vector work and two gathers.
+    dense vector work and two gathers.  On a TPU the message gather is
+    the Pallas kernel ``kernels/vmem_gather``, which holds the machine's
+    vertex vector in VMEM, wherever that vector fits a quarter of it
+    (:func:`takes_vmem_gather`); elsewhere it is XLA's ``x[ids]``.  Both
+    give bitwise the same messages.
 ``scatter``
     The historical gather-scatter loop (``at[dst].⊕(x[src] ⊗ w)``, one
     scatter per direction).  Kept as the oracle every other backend is
@@ -89,9 +93,12 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
 from ..kernels.bsr_spmv import get_semiring
 from ..kernels.bsr_spmv.kernel import spmv_pallas
+from ..kernels.vmem_gather import TILE, table_bytes, vmem_gather
 from .engine import exchange, to_device
 
 #: weight kinds an app may ask for: the stored ⊗ operand per edge
@@ -306,8 +313,9 @@ class SegmentLayout:
         valid = np.concatenate([rt.edge_valid, rt.edge_valid], axis=1)
         w_raw = _edge_operand(rt, weights)
         w = np.concatenate([w_raw, w_raw], axis=1).astype(np.float32)
-        # the dump run holds at least one row, and rows fill whole lanes
-        n = -(-(out.shape[1] + 1) // 128) * 128
+        # the dump run holds at least one row, and rows fill whole
+        # (8, 128) tiles, as the VMEM gather reads them
+        n = -(-(out.shape[1] + 1) // TILE) * TILE
         pad = ((0, 0), (0, n - out.shape[1]))
         out = np.pad(np.where(valid, out, vmax), pad, constant_values=vmax)
         inc = np.pad(np.where(valid, inc, 0), pad)
@@ -351,6 +359,15 @@ def segmented_scan(vals, level, passes: int, plus):
     return vals
 
 
+def takes_vmem_gather(vmax: int) -> bool:
+    """Whether ``segment`` gathers its messages with the VMEM kernel:
+    on a TPU, where one machine's padded ``(vmax,)`` vector fits a
+    quarter of a core's VMEM."""
+    if jax.default_backend() != "tpu":
+        return False
+    return table_bytes(vmax) <= pltpu.get_tpu_info().vmem_capacity_bytes // 4
+
+
 def _segment_prepare_factory(message_dtype: str = "float32"):
     def prepare(rt, semiring: str, weights: str):
         sr = get_semiring(semiring)
@@ -362,12 +379,17 @@ def _segment_prepare_factory(message_dtype: str = "float32"):
                   "eb_seg_w": to_device(lay.w),
                   "eb_seg_level": to_device(lay.level),
                   "eb_seg_end": to_device(lay.run_end)}
+        kernel = takes_vmem_gather(rt.vmax)
+        obs.count("bsp.vmem_gathers", int(kernel))
 
         def combine(sa, x):
-            # low-precision messages, full-precision ⊕: ⊗ in message_dtype,
-            # products back to the state dtype before the reduction
-            vals = sr.times(sa["eb_seg_w"],
-                            x.astype(mdt)[sa["eb_seg_in"]]).astype(x.dtype)
+            # the state is gathered and then cast: bitwise the messages
+            # of casting first.  Low-precision messages, full-precision
+            # ⊕: ⊗ in message_dtype, products back to the state dtype
+            # before the reduction
+            ids = sa["eb_seg_in"]
+            msgs = (vmem_gather(x, ids) if kernel else x[ids]).astype(mdt)
+            vals = sr.times(sa["eb_seg_w"], msgs).astype(x.dtype)
             scanned = segmented_scan(vals, sa["eb_seg_level"], lay.passes,
                                      sr.plus)
             return scanned[sa["eb_seg_end"]]
