@@ -49,8 +49,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 import jax  # noqa: E402
 
 from repro import obs  # noqa: E402
-from repro.bsp import (PartitionRuntime, RunOptions, bfs,  # noqa: E402
-                       build_app, make_fused_runner, pagerank, ref, sssp)
+from repro.bsp import (PartitionRuntime, bfs, build_app,  # noqa: E402
+                       make_fused_runner, pagerank, ref, sssp)
 from repro.core import scaled_paper_cluster  # noqa: E402
 from repro.data import graph500, road_mesh  # noqa: E402
 from repro.launch.cache import enable_compile_cache  # noqa: E402
@@ -155,11 +155,9 @@ def judge(app: str, out: np.ndarray, actives, pr_ref, dist_ref) -> dict:
             "depth": float(out[np.isfinite(out)].max())}
 
 
-APP_ENTRY = {"pagerank": lambda rt, root, o: pagerank(rt, PR_ITERS,
-                                                      options=o),
-             "bfs": lambda rt, root, o: bfs(rt, root, MAX_STEPS, options=o),
-             "sssp": lambda rt, root, o: sssp(rt, root, MAX_STEPS,
-                                              options=o)}
+APP_ENTRY = {"pagerank": lambda rt, root, **kw: pagerank(rt, PR_ITERS, **kw),
+             "bfs": lambda rt, root, **kw: bfs(rt, root, MAX_STEPS, **kw),
+             "sssp": lambda rt, root, **kw: sssp(rt, root, MAX_STEPS, **kw)}
 
 
 def app_kwargs(app: str, root: int) -> dict:
@@ -175,10 +173,9 @@ def phase_graph500(seed: int) -> dict:
     pr_ref, dist_ref, ref_s = references(g, root)
     runs = []
     for backend in BACKENDS:
-        opts = RunOptions(backend=backend, fused=True)
         for app, entry in APP_ENTRY.items():
             c0, t = compile_seconds(), time.perf_counter()
-            out, actives = entry(rt, root, opts)
+            out, actives = entry(rt, root, backend=backend, fused=True)
             call_s = time.perf_counter() - t
             compile_s = compile_seconds() - c0
             runs.append({"backend": backend, "app": app,
@@ -279,7 +276,7 @@ def phase_mesh(seed: int, chips: int) -> dict:
     mesh = make_mesh((chips,), ("machines",))
     runs = []
     for app in ("pagerank", "bfs"):
-        one, _ = APP_ENTRY[app](rt, root, RunOptions(fused=True))
+        one, _ = APP_ENTRY[app](rt, root, fused=True)
         spec = build_app(rt, app, **app_kwargs(app, root))
         runner = make_fused_runner(spec.superstep, spec.static, mesh=mesh,
                                    check_rep=spec.check_rep)

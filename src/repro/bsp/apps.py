@@ -37,11 +37,12 @@ mask the ``scatter`` backend's ``frontier_cap`` compaction keys on to
 make supersteps O(frontier) instead of O(E_local).  BFS's frontier
 (``dist == step``) already is that mask.
 
-Every app wrapper runs on either engine runner: the per-step oracle
-(``run_bsp``, default) or the fused on-device loop (``fused=True`` /
-``tol=`` → ``run_bsp_fused``), and backend opts such as
-``message_dtype="bfloat16"`` flow through ``**backend_opts``.  Each call
-is one ``bsp.call`` request of ``repro.obs``: ``bsp.prepare``, the
+Every app wrapper takes its knobs as keywords, those of
+:class:`RunOptions` and the backend's own: ``pagerank(rt, 20,
+backend="segment", fused=True, message_dtype="bfloat16")``.  It runs on
+either engine runner: the per-step oracle (``run_bsp``, default) or the
+fused on-device loop (``fused=True`` / ``tol=`` → ``run_bsp_fused``).
+Each call is one ``bsp.call`` request of ``repro.obs``: ``bsp.prepare``, the
 supersteps, ``bsp.gather``.  With ``mesh=`` the prepare uploads each
 machine's rows straight to its own device, so the supersteps move no
 array between devices but the exchange's all-reduce.
@@ -56,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from .backends import BACKENDS, MESSAGE_DTYPES, default_backend, get_backend
+from .backends import get_backend
 from .engine import placed_on, run_bsp, run_bsp_fused, to_device, to_host
 from .partition_runtime import PartitionRuntime
 
@@ -68,23 +69,19 @@ MONOTONE_APPS = ("bfs", "cc", "sssp")
 
 @dataclasses.dataclass(frozen=True)
 class RunOptions:
-    """The engine/backend knobs every BSP app shares, validated once.
+    """The knobs an app call takes as keywords, checked once per call.
 
-    The four app wrappers used to re-declare ``backend / fused / tol /
-    chunk / message_dtype / frontier_cap`` individually; this dataclass
-    is the single surface for them.  Pass ``options=RunOptions(...)`` to
-    any app (or ``launch/partition.py``); the individual kwargs remain
-    as a legacy spelling that assembles one internally — mixing both
-    raises.
-
-    * ``backend`` — edge-kernel backend (``bsp/backends.py``); None
-      picks ``segment``, or ``scatter`` when ``frontier_cap`` is given.
+    * ``backend`` — edge-kernel backend (``bsp/backends.py``); None picks
+      :func:`~.backends.default_backend`.
     * ``fused`` — run the whole iteration as one on-device dispatch.
     * ``tol`` — PageRank residual early-exit (implies ``fused``); the
       monotone apps (:data:`MONOTONE_APPS`) reject it.
     * ``chunk`` — fused-runner scan chunk (steps per convergence check).
     * ``message_dtype`` — message precision (see ``MESSAGE_DTYPES``).
     * ``frontier_cap`` — scatter-only frontier compaction width.
+
+    :meth:`validate` checks the runner's rules; the backend's are
+    :func:`~.backends.get_backend`'s alone.
     """
 
     backend: str | None = None
@@ -94,21 +91,8 @@ class RunOptions:
     message_dtype: str = "float32"
     frontier_cap: int | None = None
 
-    def __post_init__(self):
-        if self.backend is None:
-            object.__setattr__(self, "backend",
-                               default_backend(self.frontier_cap))
-
     def validate(self, app: str | None = None) -> "RunOptions":
-        """Raise ``ValueError`` on bad knobs / combinations; returns self."""
-        if self.backend not in BACKENDS:
-            raise ValueError(f"unknown edge-kernel backend "
-                             f"{self.backend!r} "
-                             f"(choices: {sorted(BACKENDS)})")
-        if self.message_dtype not in MESSAGE_DTYPES:
-            raise ValueError(f"unknown message_dtype "
-                             f"{self.message_dtype!r} (choices: "
-                             f"{list(MESSAGE_DTYPES)})")
+        """Raise ``ValueError`` on bad runner knobs; returns self."""
         if self.chunk < 1:
             raise ValueError(f"chunk must be >= 1, got {self.chunk}")
         if self.tol is not None and app in MONOTONE_APPS:
@@ -117,50 +101,7 @@ class RunOptions:
                 f"and already exits on an empty changed-set — valid "
                 f"choices: tol=None here, or tol with app='pagerank' "
                 f"(use fused=True for the one-dispatch runner)")
-        if self.frontier_cap is not None and self.backend != "scatter":
-            raise ValueError(
-                f"frontier_cap is a 'scatter'-backend knob (frontier "
-                f"compaction); backend {self.backend!r} does not take it "
-                f"— valid choices: backend='scatter', or frontier_cap="
-                f"None")
         return self
-
-    def backend_opts(self) -> dict:
-        """The knobs that flow to ``get_backend`` for this run."""
-        opts = {"message_dtype": self.message_dtype}
-        if self.frontier_cap is not None:
-            opts["frontier_cap"] = self.frontier_cap
-        return opts
-
-
-def _options(options: RunOptions | None, app: str, backend, fused, tol,
-             chunk, backend_opts: dict):
-    """Resolve ``options=`` vs the legacy per-kwarg spelling.
-
-    Returns ``(RunOptions, extra_backend_opts)`` — extras are
-    backend-specific knobs outside the shared surface (e.g. the pallas
-    ``block_size``/``interpret``), which pass through either way.
-    """
-    extra = dict(backend_opts)
-    if options is not None:
-        mixed = [name for name, val, default in
-                 (("backend", backend, None), ("fused", fused, False),
-                  ("tol", tol, None), ("chunk", chunk, 8))
-                 if val != default]
-        mixed += sorted(k for k in ("message_dtype", "frontier_cap")
-                        if k in extra)
-        if mixed:
-            raise ValueError(
-                f"got both options=RunOptions(...) and the individual "
-                f"kwarg(s) {mixed} — pass the shared knobs one way or "
-                f"the other")
-    else:
-        options = RunOptions(
-            backend=backend, fused=fused, tol=tol, chunk=chunk,
-            message_dtype=extra.pop("message_dtype", "float32"),
-            frontier_cap=extra.pop("frontier_cap", None))
-    options.validate(app)
-    return options, extra
 
 
 def _static_tree(rt: PartitionRuntime):
@@ -210,7 +151,7 @@ def _resolve(rt, backend, semiring: str, weights: str, exchange_mode: str,
 def _run(spec: "AppSpec", num_steps: int, *, mesh=None, fused=False,
          tol=None, chunk=8):
     """Dispatch an :class:`AppSpec` to the stepwise or fused runner."""
-    if fused or tol is not None:
+    if fused:
         return run_bsp_fused(spec.superstep, spec.state, spec.static,
                              num_steps, mesh=mesh, check_rep=spec.check_rep,
                              chunk=chunk, tol=tol)
@@ -218,20 +159,28 @@ def _run(spec: "AppSpec", num_steps: int, *, mesh=None, fused=False,
                    mesh=mesh, check_rep=spec.check_rep)
 
 
-def _call(app: str, rt, opts: RunOptions, num_steps: int, mesh,
+def _call(app: str, rt, num_steps: int, mesh, options: dict,
           build: Callable[..., "AppSpec"], *args, **kw):
     """One app call as a ``bsp.call`` request: prepare (``build(*args,
-    **kw)`` with the options' backend: the backend's layout, the static
-    tree and the initial state, uploaded to the mesh's devices if one is
-    given), run, gather.  Returns ``(global result, actives)``."""
-    runner = "fused" if opts.fused or opts.tol is not None else "stepwise"
-    with obs.request("bsp.call", app=app, runner=runner,
-                     backend=opts.backend, supersteps=num_steps,
+    **kw)`` with the call's backend: its layout, the static tree and the
+    initial state, uploaded to the mesh's devices if one is given), run,
+    gather.  ``options`` are the call's knobs: the :class:`RunOptions`
+    fields, and what is left goes to the backend (the pallas
+    ``block_size``/``interpret``).  Returns ``(global result, actives)``.
+    """
+    names = [f.name for f in dataclasses.fields(RunOptions)]
+    opts = RunOptions(**{k: options.pop(k) for k in names if k in options})
+    opts.validate(app)
+    eb = get_backend(opts.backend, message_dtype=opts.message_dtype,
+                     frontier_cap=opts.frontier_cap, **options)
+    fused = opts.fused or opts.tol is not None
+    with obs.request("bsp.call", app=app,
+                     runner="fused" if fused else "stepwise",
+                     backend=eb.name, supersteps=num_steps,
                      devices=1 if mesh is None else mesh.size):
         with obs.span("bsp.prepare"), placed_on(mesh):
-            spec = build(*args, backend=opts.backend,
-                         **opts.backend_opts(), **kw)
-        out, actives = _run(spec, num_steps, mesh=mesh, fused=opts.fused,
+            spec = build(*args, backend=eb, **kw)
+        out, actives = _run(spec, num_steps, mesh=mesh, fused=fused,
                             tol=opts.tol, chunk=opts.chunk)
         with obs.span("bsp.gather"):
             return spec.finalize(rt, out), actives
@@ -286,21 +235,18 @@ def build_pagerank(rt: PartitionRuntime, damping: float = 0.85, *,
 
 
 def pagerank(rt: PartitionRuntime, num_iters: int = 20,
-             damping: float = 0.85, *, mesh=None, options=None,
-             backend=None, init: np.ndarray | None = None,
-             fused=False, tol=None, chunk=8, **backend_opts):
+             damping: float = 0.85, *, mesh=None,
+             init: np.ndarray | None = None, **options):
     """Returns (V,) global PageRank after ``num_iters`` supersteps.
 
     ``init`` warm-starts from a previous (V,) result (see
-    :func:`build_pagerank`).  ``fused=True`` runs the whole iteration as
-    one on-device dispatch (``run_bsp_fused``); ``tol`` additionally
-    stops early once ``‖pr_{t+1} − pr_t‖∞ ≤ tol`` (and implies fused).
-    ``options=RunOptions(...)`` carries the shared engine knobs in one
-    validated object."""
-    opts, extra = _options(options, "pagerank", backend, fused, tol,
-                           chunk, backend_opts)
-    return _call("pagerank", rt, opts, num_iters, mesh, build_pagerank,
-                 rt, damping, init=init, **extra)
+    :func:`build_pagerank`).  ``options`` are the knobs of
+    :class:`RunOptions` and the backend's own: ``fused=True`` runs the
+    whole iteration as one on-device dispatch (``run_bsp_fused``);
+    ``tol`` additionally stops early once ``‖pr_{t+1} − pr_t‖∞ ≤ tol``
+    (and implies fused)."""
+    return _call("pagerank", rt, num_iters, mesh, options, build_pagerank,
+                 rt, damping, init=init)
 
 
 # ---------------------------------------------------------------------------
@@ -339,12 +285,9 @@ def build_relax(rt: PartitionRuntime, source: int, weighted: bool, *,
 
 
 def sssp(rt: PartitionRuntime, source: int = 0, num_iters: int = 30,
-         *, mesh=None, options=None, backend=None, fused=False,
-         tol=None, chunk=8, **backend_opts):
-    opts, extra = _options(options, "sssp", backend, fused, tol, chunk,
-                           backend_opts)
-    return _call("sssp", rt, opts, num_iters, mesh, build_relax,
-                 rt, source, weighted=True, **extra)
+         *, mesh=None, **options):
+    return _call("sssp", rt, num_iters, mesh, options, build_relax,
+                 rt, source, weighted=True)
 
 
 # ---------------------------------------------------------------------------
@@ -381,12 +324,8 @@ def build_bfs(rt: PartitionRuntime, source: int, *, backend=None,
 
 
 def bfs(rt: PartitionRuntime, source: int = 0, num_iters: int = 30,
-        *, mesh=None, options=None, backend=None, fused=False,
-        tol=None, chunk=8, **backend_opts):
-    opts, extra = _options(options, "bfs", backend, fused, tol, chunk,
-                           backend_opts)
-    return _call("bfs", rt, opts, num_iters, mesh, build_bfs,
-                 rt, source, **extra)
+        *, mesh=None, **options):
+    return _call("bfs", rt, num_iters, mesh, options, build_bfs, rt, source)
 
 
 # ---------------------------------------------------------------------------
@@ -419,13 +358,9 @@ def build_components(rt: PartitionRuntime, *, backend=None,
 
 
 def connected_components(rt: PartitionRuntime, num_iters: int = 30,
-                         *, mesh=None, options=None, backend=None,
-                         fused=False, tol=None, chunk=8, **backend_opts):
+                         *, mesh=None, **options):
     """Min-label propagation; returns (V,) component id per vertex."""
-    opts, extra = _options(options, "cc", backend, fused, tol, chunk,
-                           backend_opts)
-    return _call("cc", rt, opts, num_iters, mesh, build_components,
-                 rt, **extra)
+    return _call("cc", rt, num_iters, mesh, options, build_components, rt)
 
 
 #: app name -> AppSpec builder (benchmarks/dryrun iterate this)

@@ -195,9 +195,12 @@ def frontier_entries(rt, changed: np.ndarray) -> np.ndarray:
 
 def _scatter_prepare_factory(message_dtype: str = "float32",
                              frontier_cap: int | None = None):
+    mdt = _message_dtype(message_dtype)
+    if frontier_cap is not None and frontier_cap < 1:
+        raise ValueError(f"frontier_cap must be >= 1, got {frontier_cap}")
+
     def prepare(rt, semiring: str, weights: str):
         sr = get_semiring(semiring)
-        mdt = _message_dtype(message_dtype)
         wkind = weights
 
         if frontier_cap is None:
@@ -233,8 +236,6 @@ def _scatter_prepare_factory(message_dtype: str = "float32",
         # The combine compacts the live *vertices* (an O(Vmax) scan)
         # and expands only their rows: O(frontier · dmax) edge work.
         cap = int(frontier_cap)
-        if cap < 1:
-            raise ValueError(f"frontier_cap must be >= 1, got {cap}")
         w_raw = _edge_operand(rt, weights)
         p, vmax = rt.p, rt.vmax
         src2 = np.concatenate([rt.local_edges[:, :, 0],
@@ -369,9 +370,10 @@ def takes_vmem_gather(vmax: int) -> bool:
 
 
 def _segment_prepare_factory(message_dtype: str = "float32"):
+    mdt = _message_dtype(message_dtype)
+
     def prepare(rt, semiring: str, weights: str):
         sr = get_semiring(semiring)
-        mdt = _message_dtype(message_dtype)
         lay = rt.layout(("segment", sr.name, weights, str(message_dtype)),
                         lambda: SegmentLayout.build(rt, sr.name, weights,
                                                     message_dtype))
@@ -406,9 +408,10 @@ def _segment_prepare_factory(message_dtype: str = "float32"):
 def _pallas_prepare_factory(block_size: int = 128,
                             interpret: bool | None = None,
                             message_dtype: str = "float32"):
+    mdt = _message_dtype(message_dtype)
+
     def prepare(rt, semiring: str, weights: str):
         sr = get_semiring(semiring)
-        mdt = _message_dtype(message_dtype)
         bsr = rt.local_bsr(block_size=block_size, semiring=sr.name,
                            weights=weights, dtype=str(message_dtype))
         extras = {"eb_bsr_cols": to_device(bsr.cols),
@@ -430,27 +433,25 @@ def _pallas_prepare_factory(block_size: int = 128,
     return prepare
 
 
+#: name -> (description, prepare factory, shard_map replication check)
 _REGISTRY = {
-    "scatter": lambda message_dtype="float32", frontier_cap=None, **kw:
-        EdgeBackend(
-            "scatter", "gather-scatter oracle (at[].⊕ per direction)",
-            _scatter_prepare_factory(message_dtype, frontier_cap), **kw),
-    "segment": lambda message_dtype="float32", **kw: EdgeBackend(
-        "segment", "segmented scan over the destination-sorted incidence",
-        _segment_prepare_factory(message_dtype), **kw),
-    "pallas": lambda block_size=128, interpret=None,
-        message_dtype="float32", **kw: EdgeBackend(
-            "pallas", "blocked Block-ELL semiring SpMV (kernels/bsr_spmv)",
-            _pallas_prepare_factory(block_size, interpret, message_dtype),
-            check_rep=False, **kw),
+    "scatter": ("gather-scatter oracle (at[].⊕ per direction)",
+                _scatter_prepare_factory, True),
+    "segment": ("segmented scan over the destination-sorted incidence",
+                _segment_prepare_factory, True),
+    "pallas": ("blocked Block-ELL semiring SpMV (kernels/bsr_spmv)",
+               _pallas_prepare_factory, False),
 }
 
 BACKENDS = tuple(_REGISTRY)
 
 
-def get_backend(name=None, **opts) -> EdgeBackend:
+def get_backend(name=None, *, frontier_cap: int | None = None,
+                **opts) -> EdgeBackend:
     """Resolve a backend by name (``EdgeBackend`` passes through, ``None``
-    is :func:`default_backend`).
+    is :func:`default_backend`), checking its knobs: the one place that
+    raises ``ValueError`` on an unknown backend, message dtype or
+    misplaced ``frontier_cap``.
 
     ``opts`` are backend-specific: every backend takes ``message_dtype``
     (default ``"float32"``; ``"bfloat16"`` is the low-precision message
@@ -462,10 +463,17 @@ def get_backend(name=None, **opts) -> EdgeBackend:
     if isinstance(name, EdgeBackend):
         return name
     if name is None:
-        name = default_backend(opts.get("frontier_cap"))
+        name = default_backend(frontier_cap)
     try:
-        factory = _REGISTRY[name]
+        description, factory, check_rep = _REGISTRY[name]
     except KeyError:
         raise ValueError(f"unknown edge-kernel backend {name!r} "
                          f"(choices: {sorted(_REGISTRY)})") from None
-    return factory(**opts)
+    if frontier_cap is not None:
+        if name != "scatter":
+            raise ValueError(
+                f"frontier_cap is a 'scatter'-backend knob (frontier "
+                f"compaction); backend {name!r} does not take it — valid "
+                f"choices: backend='scatter', or frontier_cap=None")
+        opts["frontier_cap"] = frontier_cap
+    return EdgeBackend(name, description, factory(**opts), check_rep)

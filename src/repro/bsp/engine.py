@@ -1,12 +1,11 @@
 """BSP superstep engine.
 
-One *machine* = one lane of the ``machines`` axis.  The same superstep body
-runs under
-
-* ``jax.vmap(..., axis_name="machines")`` — single-device simulation (CPU
-  tests, benchmarking), or
-* ``jax.shard_map`` over a ``machines`` mesh axis — real multi-device runs
-  (the multi-pod path; collectives become ICI traffic).
+One *machine* = one lane of the ``machines`` axis.  :func:`_on_machines`
+maps a per-machine function over the machine-stacked arrays, the one
+place that decides how machines meet devices: with no mesh the machines
+are the lanes of ``jax.vmap(..., axis_name="machines")`` on one device;
+on a ``machines`` mesh each device runs its own machine under
+``jax.shard_map``, and the collectives become ICI traffic.
 
 The replica exchange is the only cross-machine communication: a psum (or
 pmin/pmax) over an (R+1,)-sized buffer — fixed shape, proportional to the
@@ -176,46 +175,70 @@ class _Exchanging:
         return out
 
 
+def _on_machines(superstep: Callable, mesh: Mesh | None, check_rep: bool,
+                 body: Callable = lambda step, st, sa: step(st, sa)):
+    """Map ``body(superstep, state, static, *shared)``, written for one
+    machine, over machine-stacked ``state`` and ``static``; ``shared``
+    reaches every machine whole, and every output comes back stacked.
+
+    With no mesh the machines are the lanes of ``jax.vmap``; on a mesh
+    each device runs its own machine under ``jax.shard_map``
+    (``check_rep=False`` drops its replication check, which ops with no
+    replication rule, such as ``pallas_call``, need).  Returns
+    ``(mapped, count_exchanges)``: on a mesh ``count_exchanges(steps)``
+    counts the bytes ``steps`` supersteps' exchanges all-reduce per
+    machine (``bsp.exchange_bytes``); under vmap nothing crosses a chip
+    and it counts nothing.
+    """
+    if mesh is None:
+        def mapped(state, static, *shared):
+            return jax.vmap(lambda st, sa: body(superstep, st, sa, *shared),
+                            axis_name=MACHINES)(state, static)
+
+        return mapped, lambda steps: None
+
+    superstep = _Exchanging(superstep)
+
+    def per_device(state, static, *shared):
+        st, sa = jax.tree.map(lambda a: a[0], (state, static))
+        out = body(superstep, st, sa, *shared)
+        return jax.tree.map(lambda a: jnp.asarray(a)[None], out)
+
+    def mapped(state, static, *shared):
+        return jax.shard_map(
+            per_device, mesh=mesh,
+            in_specs=(P(MACHINES), P(MACHINES)) + (P(),) * len(shared),
+            out_specs=P(MACHINES), check_vma=check_rep)(
+                state, static, *shared)
+
+    def count_exchanges(steps: int) -> None:
+        obs.count("bsp.exchange_bytes", steps * superstep.nbytes)
+
+    return mapped, count_exchanges
+
+
 def make_step(superstep: Callable, static, *, mesh: Mesh | None = None,
               check_rep: bool = True):
     """Compile one BSP superstep: state -> (state, (p,) active counts).
 
-    The returned callable also offers ``.lower(state)``.
-    ``check_rep=False`` disables shard_map's replication check — required
-    when the superstep body contains ops without a replication rule
-    (``pallas_call``; the edge-kernel backends declare this).  On a mesh
-    each call counts the superstep's ``bsp.exchange_bytes``.
+    The returned callable also offers ``.lower(state)``.  ``check_rep``
+    goes to :func:`_on_machines`; on a mesh each call counts the
+    superstep's ``bsp.exchange_bytes``.
     """
-    if mesh is None:
-        def vstep(state, static):
-            _count_trace()
-            return jax.vmap(superstep, axis_name=MACHINES)(state, static)
-
-        return _bind_static(jax.jit(vstep), static)
-
-    state_spec_of = lambda tree: jax.tree.map(lambda _: P(MACHINES), tree)
-    superstep = _Exchanging(superstep)
+    mapped, count_exchanges = _on_machines(superstep, mesh, check_rep)
 
     def step(state, static):
         _count_trace()
+        return mapped(state, static)
 
-        def inner(st, sa):
-            st = jax.tree.map(lambda a: a[0], st)
-            sa = jax.tree.map(lambda a: a[0], sa)
-            new_state, active = superstep(st, sa)
-            return (jax.tree.map(lambda a: jnp.asarray(a)[None], new_state),
-                    jnp.asarray(active)[None])
-        return jax.shard_map(
-            inner, mesh=mesh,
-            in_specs=(state_spec_of(state), state_spec_of(static)),
-            out_specs=(state_spec_of(state), P(MACHINES)),
-            check_vma=check_rep)(state, static)
-
+    # under vmap the module stays jit_vstep (on a mesh, jit_step): the
+    # program's text, and so a persistent compile cache's key, stay put
+    step.__name__ = "step" if mesh is not None else "vstep"
     dispatch = _bind_static(jax.jit(step), static)
 
     def run(state):
         out = dispatch(state)
-        obs.count("bsp.exchange_bytes", superstep.nbytes)
+        count_exchanges(1)
         return out
 
     run.lower = dispatch.lower
@@ -247,7 +270,7 @@ def run_bsp(superstep: Callable, state, static, num_steps: int,
 
 
 def _state_residual(old, new) -> jnp.ndarray:
-    """Global ``‖new − old‖∞`` over every state leaf (cast to float32).
+    """``‖new − old‖∞`` over every state leaf (cast to float32).
 
     The on-device convergence measure for contraction-map apps
     (PageRank): counter/mask leaves would keep it ≥ 1, which is why the
@@ -275,11 +298,16 @@ def make_fused_runner(superstep: Callable, static, *,
     * ``tol`` set — converged when the on-device residual
       ``‖state_{t+1} − state_t‖∞ ≤ tol`` (PageRank power iteration).
 
-    After convergence every remaining chunk's while_loop exits on its
-    first predicate check, so converged tail steps cost one condition
-    evaluation instead of a superstep.  Active counts accumulate into an
-    on-device ``(chunk, p)`` buffer per chunk; the single host sync at
-    the end trims them to the steps actually run.
+    The loop is written once, per machine, and mapped by
+    :func:`_on_machines`.  Its gate reduces over the machines with a
+    collective, so every machine takes the same branch; under vmap the
+    collective's result is unbatched, and the while loop stays one
+    loop.  After convergence every remaining chunk's while_loop exits on
+    its first predicate check, so converged tail steps cost one
+    condition evaluation instead of a superstep.  Each machine's active
+    counts accumulate into an on-device ``(chunk,)`` buffer per chunk;
+    the single host sync at the end trims them to the steps actually
+    run.
 
     The compiled computation is cached on the returned callable (one
     trace per distinct chunk count), so repeat runs — benchmark loops,
@@ -289,7 +317,50 @@ def make_fused_runner(superstep: Callable, static, *,
     rebuilds its step.
     """
     chunk = max(1, int(chunk))
-    body = jax.vmap(superstep, axis_name=MACHINES)
+
+    def converged(old, new, act):
+        if tol is not None:
+            return jax.lax.pmax(_state_residual(old, new), MACHINES) <= tol
+        total = jax.lax.psum(jnp.asarray(act), MACHINES)
+        return total == jnp.zeros((), total.dtype)
+
+    def loop(step, state, static, limits):
+        act_dt = jax.eval_shape(lambda s: step(s, static)[1], state).dtype
+
+        def chunk_body(carry, limit):
+            """One scan step: while_loop over ≤ chunk supersteps, gated."""
+            st0, done0 = carry
+            # each machine's actives differ, so the buffer's initial value
+            # carries the 'varying over machines' type the loop gives it
+            buf0 = jax.lax.pcast(jnp.zeros((chunk,), act_dt), (MACHINES,),
+                                 to="varying")
+
+            def cond(c):
+                _, _, t, done = c
+                return (t < limit) & jnp.logical_not(done)
+
+            def step_once(c):
+                st, buf, t, _ = c
+                new_st, act = step(st, static)
+                # a select, not a dynamic_update_slice: vmap batches
+                # that into a scatter
+                buf = jnp.where(jnp.arange(chunk) == t, act, buf)
+                return new_st, buf, t + 1, converged(st, new_st, act)
+
+            st, buf, t, done = jax.lax.while_loop(
+                cond, step_once, (st0, buf0, jnp.int32(0), done0))
+            return (st, done), (buf, t)
+
+        (state, _), (bufs, ts) = jax.lax.scan(
+            chunk_body, (state, jnp.zeros((), bool)), limits)
+        return state, bufs, ts
+
+    mapped, count_exchanges = _on_machines(superstep, mesh, check_rep, loop)
+
+    @jax.jit
+    def fused(state, limits, static):
+        _count_trace()
+        return mapped(state, static, limits)
 
     def chunk_limits(state, num_steps):
         num_chunks = -(-num_steps // chunk)
@@ -297,98 +368,6 @@ def make_fused_runner(superstep: Callable, static, *,
         limits[-1] = num_steps - chunk * (num_chunks - 1)
         # from the host: on a mesh it goes to every device from there
         return state, limits
-
-    def chunk_body(step_fn, done_of, new_buf, carry, limit):
-        """One scan step: while_loop over ≤ chunk supersteps, gated."""
-        st0, done0 = carry
-        buf0 = new_buf()
-
-        def cond(c):
-            _, _, t, dn = c
-            return (t < limit) & jnp.logical_not(dn)
-
-        def step_once(c):
-            st, buf, t, _ = c
-            new_st, act = step_fn(st)
-            buf = jax.lax.dynamic_update_index_in_dim(buf, act, t, 0)
-            return (new_st, buf, t + 1,
-                    done_of(st, new_st, act))
-
-        st, buf, t, done = jax.lax.while_loop(
-            cond, step_once, (st0, buf0, jnp.int32(0), done0))
-        return (st, done), (buf, t)
-
-    if mesh is None:
-        def done_of(st, new_st, act):
-            if tol is not None:
-                return _state_residual(st, new_st) <= tol
-            return act.sum() == jnp.zeros((), act.dtype)
-
-        @jax.jit
-        def fused(state, limits, static):
-            _count_trace()
-            p = _num_machines(state)
-            act_dt = jax.eval_shape(body, state, static)[1].dtype
-            run = functools.partial(
-                chunk_body, lambda st: body(st, static), done_of,
-                lambda: jnp.zeros((chunk, p), act_dt))
-            (st, _), (bufs, ts) = jax.lax.scan(
-                run, (state, jnp.zeros((), bool)), limits)
-            return st, bufs, ts
-
-        dispatch = _bind_static(fused, static, chunk_limits)
-
-        def run(state, num_steps: int):
-            p = _num_machines(state)
-            if num_steps <= 0:
-                return state, np.zeros((0, p))
-            state, bufs, ts = dispatch(state, num_steps)
-            steps = int(to_host(ts).sum())
-            actives = to_host(bufs).reshape(-1, p)[:steps]
-            return state, actives
-
-        run.lower = dispatch.lower
-        return run
-
-    # shard_map: the fused loop runs rank-reduced per device; the gate
-    # reduces with a collective so every device agrees on the predicate
-    state_spec_of = lambda tree: jax.tree.map(lambda _: P(MACHINES), tree)
-    superstep = _Exchanging(superstep)
-
-    def sharded(state_b, limits, static_b):
-        st = jax.tree.map(lambda a: a[0], state_b)
-        sa = jax.tree.map(lambda a: a[0], static_b)
-        act_dt = jax.eval_shape(lambda s: superstep(s, sa)[1], st).dtype
-
-        def done_of(old, new, act):
-            if tol is not None:
-                res = jax.lax.pmax(_state_residual(old, new), MACHINES)
-                return res <= tol
-            tot = jax.lax.psum(jnp.asarray(act), MACHINES)
-            return tot == jnp.zeros((), tot.dtype)
-
-        def step_fn(st):
-            new_st, act = superstep(st, sa)
-            return new_st, jnp.asarray(act)
-
-        # each device's actives buffer differs, so its initial value
-        # must carry the 'varying over machines' type the loop body gives it
-        new_buf = lambda: jax.lax.pcast(jnp.zeros((chunk,), act_dt),
-                                        (MACHINES,), to="varying")
-        run = functools.partial(chunk_body, step_fn, done_of, new_buf)
-        (st, _), (bufs, ts) = jax.lax.scan(
-            run, (st, jnp.zeros((), bool)), limits)
-        return (jax.tree.map(lambda a: jnp.asarray(a)[None], st),
-                jnp.asarray(bufs)[None], jnp.asarray(ts)[None])
-
-    @jax.jit
-    def fused(state, limits, static):
-        _count_trace()
-        return jax.shard_map(
-            sharded, mesh=mesh,
-            in_specs=(state_spec_of(state), P(), state_spec_of(static)),
-            out_specs=(state_spec_of(state), P(MACHINES), P(MACHINES)),
-            check_vma=check_rep)(state, limits, static)
 
     dispatch = _bind_static(fused, static, chunk_limits)
 
@@ -398,7 +377,7 @@ def make_fused_runner(superstep: Callable, static, *,
             return state, np.zeros((0, p))
         state, bufs, ts = dispatch(state, num_steps)
         steps = int(to_host(ts)[0].sum())
-        obs.count("bsp.exchange_bytes", steps * superstep.nbytes)
+        count_exchanges(steps)
         # bufs: (p, num_chunks, chunk) -> (num_chunks*chunk, p), trimmed
         actives = to_host(bufs).transpose(1, 2, 0).reshape(-1, p)[:steps]
         return state, actives

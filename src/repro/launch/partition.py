@@ -203,11 +203,11 @@ def main(argv=None):
 
 def _run_pagerank(rt, args) -> None:
     """Distributed PageRank on the fresh partition via --backend."""
-    from ..bsp import RunOptions, pagerank
-    opts = RunOptions(backend=args.backend, fused=args.fused, tol=args.tol,
-                      message_dtype=args.message_dtype)
+    from ..bsp import pagerank
     t0 = time.perf_counter()
-    pr, actives = pagerank(rt, num_iters=args.pagerank_iters, options=opts)
+    pr, actives = pagerank(rt, num_iters=args.pagerank_iters,
+                           backend=args.backend, fused=args.fused,
+                           tol=args.tol, message_dtype=args.message_dtype)
     dt = time.perf_counter() - t0
     top = np.argsort(pr)[::-1][:5]
     steps = len(actives)

@@ -21,8 +21,7 @@ from repro.bsp import (PartitionRuntime, bfs, build_app, connected_components,
                        get_backend, pagerank, ref, sssp)
 from repro.core import scaled_paper_cluster, windgp
 from repro.data import graph500, rmat
-from repro.bsp import RunOptions
-from repro.bsp.backends import DEFAULT_BACKEND
+from repro.bsp.backends import DEFAULT_BACKEND, default_backend
 from repro.kernels.bsr_spmv import get_semiring
 
 PALLAS_OPTS = {"block_size": 16}
@@ -133,8 +132,7 @@ def test_pagerank_per_vertex_relative_error(backend):
     g = graph500(12, seed=0)
     cl = scaled_paper_cluster(2, 6, g.num_edges)
     rt = PartitionRuntime.create(source=g, method="windgp", cluster=cl)
-    got, _ = pagerank(rt, 20, options=RunOptions(backend=backend,
-                                                 fused=True))
+    got, _ = pagerank(rt, 20, backend=backend, fused=True)
     want = ref.pagerank(g, 20)
     assert np.max(np.abs(got - want) / want) <= 1e-4
 
@@ -196,8 +194,8 @@ def test_the_default_backend_is_segment(part, app):
     _, _, rt = part
     assert DEFAULT_BACKEND == "segment"
     assert get_backend().name == "segment"
-    assert RunOptions().backend == "segment"
-    assert RunOptions(frontier_cap=8).backend == "scatter"
+    assert default_backend() == "segment"
+    assert default_backend(frontier_cap=8) == "scatter"
     assert get_backend(frontier_cap=8).name == "scatter"
     spec = build_app(rt, app)
     assert "eb_seg_in" in spec.static and "edges" not in spec.static

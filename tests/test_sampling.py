@@ -15,8 +15,8 @@ Five contracts, each tested here:
   isolated-vertex seeds handled as all-``-1`` lanes;
 * ``PartitionRuntime.create`` routes by keywords, builds bit-identical
   runtimes to every legacy constructor, and rejects conflicting routes;
-  ``RunOptions`` validates the shared app knobs once (tol on a monotone
-  app, frontier_cap off-scatter, options=+kwargs mixing);
+  an app's keyword knobs are checked once (tol on a monotone app, an
+  unknown backend, frontier_cap off-scatter);
 * the registry's knob errors name the offending partitioner and its
   valid knobs, and ``windgp``'s ``train_balance`` knob reduces
   train-vertex skew while the default stays bit-identical.
@@ -27,8 +27,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.bsp import (MONOTONE_APPS, PartitionRuntime, RunOptions,
-                       StreamAssignment, pagerank, sssp)
+from repro.bsp import (MONOTONE_APPS, PartitionRuntime, StreamAssignment,
+                       get_backend, sssp)
 from repro.core import from_edge_list, scaled_paper_cluster
 from repro.core import partitioners as registry
 from repro.core.partition_state import PartitionState, edge_incidence_counts
@@ -281,35 +281,20 @@ class TestCreateFacade:
 
 
 class TestRunOptions:
-    def test_options_equals_legacy_kwargs_bitwise(self, small):
-        g, cl, assign = small
-        rt = PartitionRuntime.create(g, assign=assign, cluster=cl)
-        legacy, _ = pagerank(rt, num_iters=5, backend="segment")
-        via_opts, _ = pagerank(rt, num_iters=5,
-                               options=RunOptions(backend="segment"))
-        assert np.array_equal(np.asarray(legacy), np.asarray(via_opts))
-
     def test_tol_rejected_on_monotone_apps(self, small):
         g, cl, assign = small
         rt = PartitionRuntime.create(g, assign=assign, cluster=cl)
         assert "sssp" in MONOTONE_APPS
         with pytest.raises(ValueError, match="monotone"):
-            sssp(rt, source=0, options=RunOptions(tol=1e-3))
+            sssp(rt, source=0, tol=1e-3)
 
     def test_frontier_cap_is_scatter_only(self):
         with pytest.raises(ValueError, match="scatter"):
-            RunOptions(backend="segment", frontier_cap=8).validate()
-
-    def test_mixing_options_and_kwargs_raises(self, small):
-        g, cl, assign = small
-        rt = PartitionRuntime.create(g, assign=assign, cluster=cl)
-        with pytest.raises(ValueError, match="both options="):
-            pagerank(rt, num_iters=2, backend="segment",
-                     options=RunOptions())
+            get_backend("segment", frontier_cap=8)
 
     def test_unknown_backend_named(self):
         with pytest.raises(ValueError, match="unknown edge-kernel backend"):
-            RunOptions(backend="nope").validate()
+            get_backend("nope")
 
 
 class TestRegistryKnobErrors:
